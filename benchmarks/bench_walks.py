@@ -659,7 +659,7 @@ def fused_advance() -> list[str]:
     """The fused Pallas multi-hop advance vs the plain jitted JAX advance.
 
     Runs the same RWNV workload under ``advance_impl="jax"`` and
-    ``advance_impl="pallas"`` (interpret mode on CPU CI; Mosaic on TPU),
+    ``advance_impl="pallas"`` (the Pallas interpreter; CPU only for now),
     *asserts* the walks are bit-identical (endpoint histogram CRC + step
     count + deterministic I/O charges — the kernel draws the very same
     counter-keyed threefry uniforms), and reports ``us_per_call`` for both

@@ -13,7 +13,7 @@ Validates, over ``README.md`` and every ``docs/*.md`` page:
    textually in the resolved module/package sources.
 3. **CLI flags** — every ``--flag`` mentioned must be defined by an
    ``add_argument`` call somewhere in ``src/``, ``benchmarks/``,
-   ``examples/``, or ``scripts/``.
+   ``examples/``, ``scripts/``, or a top-level script.
 
 Exit code 0 when clean; 1 with one line per violation otherwise.  Pass a
 repo root to check a different tree (used by the tests).
@@ -43,12 +43,13 @@ def doc_files(root: Path) -> list[Path]:
 
 def defined_flags(root: Path) -> set:
     flags = set(EXTERNAL_FLAGS)
+    scripts = list(root.glob("*.py"))
     for sub in ("src", "benchmarks", "examples", "scripts"):
         base = root / sub
-        if not base.is_dir():
-            continue
-        for py in base.rglob("*.py"):
-            flags.update(ADD_ARG_RE.findall(py.read_text(encoding="utf-8")))
+        if base.is_dir():
+            scripts += base.rglob("*.py")
+    for py in scripts:
+        flags.update(ADD_ARG_RE.findall(py.read_text(encoding="utf-8")))
     return flags
 
 

@@ -48,7 +48,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .engine import pair_advance_impl
 from repro.engines.step import VID_PAD, remap_search_iters
@@ -153,7 +152,8 @@ class DistributedWalkEngine:
         trivial_nv = isinstance(task.model, Node2vec) and task.model.p == task.model.q == 1.0
         self.k_max = 1 if first_order or trivial_nv else k_max
         self.n_iters = int(np.ceil(np.log2(max(bg.max_block_edges, 2)))) + 2
-        self._blocks = self._stack_blocks()
+        #: the graph's blocks on device, block b on `block_axis` rank b
+        self.block_shards = self._stack_blocks()
 
     # -- block shards ------------------------------------------------------
     def _stack_blocks(self) -> BlockShards:
@@ -358,12 +358,12 @@ class DistributedWalkEngine:
             P(self.block_axis, None),
         )
         sweep_fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 self._make_sweep(capacity),
                 mesh=self.mesh,
                 in_specs=(bspec, wspec, wspec, wspec, wspec, P()),
                 out_specs=(wspec, wspec, wspec, wspec),
-                check_rep=False,
+                check_vma=False,
             )
         )
         wsh = NamedSharding(self.mesh, wspec)
@@ -392,7 +392,7 @@ class DistributedWalkEngine:
                 cur = jax.device_put(jnp.asarray(host_cur), wsh)
                 hop = jax.device_put(jnp.asarray(host_hop), wsh)
                 alive = jax.device_put(jnp.asarray(host_alive), wsh)
-                prev, cur, hop, alive = sweep_fn(self._blocks, prev, cur, hop, alive, key)
+                prev, cur, hop, alive = sweep_fn(self.block_shards, prev, cur, hop, alive, key)
                 sweeps += 1
                 live_in = host_alive
                 host_prev = np.asarray(prev).astype(np.int32)

@@ -203,7 +203,6 @@ class EngineBase:
         writer_queue: int = 64,
         pool_shards: int = 1,
         advance_impl: str = "jax",
-        advance_interpret: bool = True,
         stats: Optional[IOStats] = None,
         block_store: Optional[BlockStore] = None,
         initial_walks: Optional[np.ndarray] = None,
@@ -244,8 +243,15 @@ class EngineBase:
         # through kernels/rng, so their walks are bit-identical
         if advance_impl not in ("jax", "pallas"):
             raise ValueError(f"advance_impl must be 'jax' or 'pallas', got {advance_impl!r}")
+        if advance_impl == "pallas" and jax.default_backend() != "cpu":
+            raise ValueError(
+                "advance_impl='pallas' runs only on the CPU, under the Pallas "
+                "interpreter: Mosaic refuses the kernel's 1-D vector gathers "
+                "(flat[mid] in repro.kernels.pair_advance._lower_bound: 'Only 2D "
+                f"gather is supported'); use advance_impl='jax' on "
+                f"{jax.default_backend()!r}"
+            )
         self.advance_impl = advance_impl
-        self.advance_interpret = bool(advance_interpret)
         # counter-based RNG: one fixed base key; draws are keyed per
         # (walk id, hop), never per call — see repro.engines.step
         self._base_key = jax.random.PRNGKey(self.seed)
@@ -377,7 +383,8 @@ class EngineBase:
         pair_args, v_iters = self.pair.device_args()
         t0 = time.perf_counter()
         if self.advance_impl == "pallas":
-            advance = partial(fused_advance_pair, interpret=self.advance_interpret)
+            # construction admits "pallas" only on the CPU backend
+            advance = partial(fused_advance_pair, interpret=True)
         else:
             advance = advance_pair
         out = advance(

@@ -31,9 +31,12 @@ reproduces :func:`repro.engines.step.pair_advance_impl` (and therefore the
 in-memory oracle) bit for bit; ``advance_impl={"jax","pallas"}`` in
 :class:`repro.engines.base.EngineBase` switches between them.
 
-``interpret=True`` (the default, and what CPU CI exercises) runs the same
-kernel body under the Pallas interpreter; on TPU pass ``interpret=False``
-to lower through Mosaic.
+The kernel runs only under the Pallas interpreter (``interpret=True``,
+which CPU CI exercises).  Mosaic refuses to lower it: the binary searches
+and row lookups index 1-D refs with vector indices (``flat[mid]`` in
+:func:`_lower_bound`), and Mosaic supports only 2-D gathers.  The engines
+therefore admit ``advance_impl="pallas"`` on the CPU backend alone, and
+``tests/test_tpu_compile.py`` pins the refusal.
 """
 
 from __future__ import annotations
@@ -269,16 +272,16 @@ def fused_advance_pair(
     record: bool,
     has_alias: bool,
     max_len: int,
+    interpret: bool,
     max_hops: int | None = None,
-    interpret: bool = True,
     walk_tile: int = WALK_TILE,
 ):
     """Drop-in fused replacement for :func:`repro.engines.step.advance_pair`.
 
     Identical argument list and return contract
     ``(prev, cur, hop, alive, steps, trace)``; bit-identical outputs.  The
-    extra statics select the Pallas lowering: ``interpret`` (CI-safe CPU
-    interpreter vs Mosaic TPU), ``walk_tile`` (grid chunk), and
+    extra statics select the Pallas lowering: ``interpret`` (the Pallas
+    interpreter vs Mosaic, which refuses this kernel), ``walk_tile`` (grid chunk), and
     ``max_hops`` (loop bound — ``None`` means the full ``max_len + 1``
     sweep; 1 gives the single-step form :mod:`repro.kernels.ops` exposes).
     """

@@ -6,22 +6,23 @@ The engines key each random draw by ``(base_key, walk_id, hop, round)``
 ``threefry2x32`` *primitive*, whose CPU/TPU lowering Mosaic cannot ingest
 inside a Pallas kernel body.  This module re-derives the same bits from
 scratch with plain ``jnp`` elementwise ops — adds, xors, rotates — which
-lower identically under jit, vmap, shard_map, and Mosaic.  Every function
-here is **bitwise identical** to its ``jax.random`` counterpart (pinned by
-``tests/test_rng.py``), so the fused Pallas advance kernel, the jitted JAX
-impl, and the distributed sweep all draw the very same uniforms.
+lower identically under jit, vmap and shard_map.  Every function here is
+**bitwise identical** to its ``jax.random`` counterpart under the
+partitionable threefry layout (``jax_threefry_partitionable=True``, the
+default of the installed jax; pinned by ``tests/test_rng.py``), so the
+fused Pallas advance kernel, the jitted JAX impl, and the distributed
+sweep all draw the very same uniforms.
 
 Keys are carried as a raw ``uint32`` pair ``(k0, k1)`` rather than jax key
 arrays: Pallas refs are flat arrays, and the pair form broadcasts — fold a
 scalar key against a ``[N]`` walk-id vector and every output is ``[N]``.
 
-Bit-compat notes (jax 0.4.37, default non-partitionable threefry):
+Bit layout (partitionable threefry; ``T(x0, x1)`` is one cipher call):
 
-* ``fold_in(key, d)`` is ``threefry2x32(key, [0, uint32(d)])``.
-* ``uniform(key, (3,))`` pads the odd count to 4 and evaluates the block
-  cipher on counter halves ``x0=[0,1], x1=[2,0]``; the bits land as
-  ``[T(0,2).out0, T(1,0).out0, T(0,2).out1]`` — two cipher calls, not
-  three.  ``uniform(key, ())`` is ``T(0,0).out0``.
+* ``fold_in(key, d)`` is ``T(0, uint32(d))``.
+* draw ``i`` of ``uniform(key, shape)`` takes the bits
+  ``T(0, i).out0 ^ T(0, i).out1`` — one cipher call per draw, so
+  ``uniform(key, ())`` is draw 0 and ``uniform(key, (3,))`` draws 0, 1, 2.
 * bits -> float32 in [0,1): ``bitcast((bits >> 9) | 0x3F800000) - 1.0``.
 """
 
@@ -81,21 +82,20 @@ def _bits_to_unit(bits):
     return jax.lax.bitcast_convert_type(mantissa, jnp.float32) - jnp.float32(1.0)
 
 
+def _draw(k0, k1, i: int):
+    """Draw ``i`` of ``jax.random.uniform`` under ``key = (k0, k1)``."""
+    y0, y1 = threefry2x32(k0, k1, jnp.uint32(0), jnp.uint32(i))
+    return _bits_to_unit(y0 ^ y1)
+
+
 def uniform1(k0, k1):
     """``jax.random.uniform(key, ())`` for every key in the pair arrays."""
-    b0, _ = threefry2x32(k0, k1, jnp.uint32(0), jnp.uint32(0))
-    return _bits_to_unit(b0)
+    return _draw(k0, k1, 0)
 
 
 def uniform3(k0, k1):
-    """``jax.random.uniform(key, (3,))`` per key: returns ``(u0, u1, u2)``.
-
-    The odd draw count makes jax pad the counter block to 4, so the three
-    values come out of two cipher evaluations in padded order.
-    """
-    a0, a1 = threefry2x32(k0, k1, jnp.uint32(0), jnp.uint32(2))
-    b0, _ = threefry2x32(k0, k1, jnp.uint32(1), jnp.uint32(0))
-    return _bits_to_unit(a0), _bits_to_unit(b0), _bits_to_unit(a1)
+    """``jax.random.uniform(key, (3,))`` per key: returns ``(u0, u1, u2)``."""
+    return _draw(k0, k1, 0), _draw(k0, k1, 1), _draw(k0, k1, 2)
 
 
 def key_halves(key):

@@ -12,7 +12,8 @@ sources concentrate on the hottest block with probability ``--skew``
 .WalkQueryServer` in admission batches of ``--max-batch``, and prints the
 per-query latency percentiles plus the hot-set pinning ledger
 (``pinned_block_hits`` / ``pinned_bytes_saved`` vs total ``block_load``
-charges).  ``--hot-blocks 0`` is the pure-LRU reference.
+charges).  ``--hot-blocks 0`` is the pure-LRU reference.  ``main(argv)``
+returns the answers and the server's :class:`~repro.core.stats.IOStats`.
 """
 
 from __future__ import annotations
@@ -22,7 +23,7 @@ import argparse
 import numpy as np
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--vertices", type=int, default=3000)
     ap.add_argument("--avg-degree", type=int, default=8)
@@ -77,7 +78,8 @@ def main():
         "--advance",
         default="jax",
         choices=("jax", "pallas"),
-        help="UpdateWalk lowering (see repro.launch.walk)",
+        help="UpdateWalk lowering: jax (every backend) or pallas (CPU "
+        "only, Pallas interpreter; see repro.launch.walk)",
     )
     ap.add_argument(
         "--graph-backend",
@@ -98,7 +100,11 @@ def main():
         help="waste budget (bytes) of the gap-aware on-demand read planner "
         "(repro.io.ioplan); 0 = planner off, per-vertex reference reads",
     )
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from repro.core import barabasi_albert, partition_into_n_blocks
     from repro.serve import QueryConfig, WalkQueryServer
@@ -150,6 +156,7 @@ def main():
             f"{s.pinned_bytes_saved},{s.ondemand_syscalls},"
             f"{s.coalesced_ranges},{s.coalesce_waste_bytes}"
         )
+    return answers, s
 
 
 if __name__ == "__main__":

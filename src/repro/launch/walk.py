@@ -7,7 +7,8 @@
         [--advance pallas]
 
 Prints the paper's headline statistics (block/vertex/on-demand I/Os,
-simulated I/O + exec time) as one CSV row per engine.
+simulated I/O + exec time) as one CSV row per engine; ``main(argv)``
+returns the :class:`~repro.engines.WalkResult` of each engine by name.
 """
 
 from __future__ import annotations
@@ -15,7 +16,7 @@ from __future__ import annotations
 import argparse
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--task", choices=("rwnv", "prnv", "deepwalk"), default="rwnv")
     ap.add_argument(
@@ -78,9 +79,11 @@ def main():
         "--advance",
         default="jax",
         choices=("jax", "pallas"),
-        help="UpdateWalk lowering: the plain jitted JAX advance or the "
-        "fused Pallas multi-hop kernel (repro.kernels.pair_advance; "
-        "interpret mode off-TPU) — walks are bit-identical either way",
+        help="UpdateWalk lowering: the plain jitted JAX advance (every "
+        "backend) or the fused Pallas multi-hop kernel "
+        "(repro.kernels.pair_advance), which runs only on the CPU under the "
+        "Pallas interpreter because Mosaic refuses its 1-D vector gathers — "
+        "walks are bit-identical either way",
     )
     ap.add_argument(
         "--graph-backend",
@@ -103,7 +106,11 @@ def main():
         "(repro.io.ioplan): holes up to this size are read through instead "
         "of seeked over; 0 = planner off, per-vertex reference reads",
     )
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+
+    from repro.launch.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     from repro.core import (
         BiBlockEngine,
@@ -157,6 +164,7 @@ def main():
         pool_shards=args.pool_shards,
     )
     engines = args.engine or ["biblock", "sogw"]
+    results = {}
     print(
         "engine,block_ios,vertex_ios,ondemand_ios,ondemand_syscalls,"
         "coalesced_ranges,coalesce_waste_bytes,walk_bytes_written,"
@@ -175,6 +183,7 @@ def main():
         else:
             # the oracle needs the whole CSR in RAM regardless of backend
             res = InMemoryWalker(bg_ram, task).run(record_walks=False)
+        results[name] = res
         s = res.stats
         hits = (res.block_store_counters or {}).get("prefetch_hits", 0)
         print(
@@ -185,6 +194,7 @@ def main():
             f"{s.writer_queue_peak},"
             f"{s.sim_io_time:.4f},{s.exec_time:.4f},{s.sim_wall_time:.4f}"
         )
+    return results
 
 
 if __name__ == "__main__":

@@ -167,7 +167,6 @@ def _shared_mlp(p, x):
 # assignment fans out to all halves, and the combine sums them.
 
 def _moe_ep(params, x, cfg: ModelConfig, mesh, ep_axis: str, dp_axes):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, D = x.shape
@@ -231,12 +230,12 @@ def _moe_ep(params, x, cfg: ModelConfig, mesh, ep_axis: str, dp_axes):
             aux = jax.lax.pmean(aux, ax)
         return out.astype(xl.dtype).reshape(Bl, Sl, D), aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(xspec, wspec, wspec, P(None, None)),
         out_specs=(xspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["experts"]["w_in"], params["experts"]["w_out"],
       params["router"].astype(jnp.float32))
     if "shared" in params:

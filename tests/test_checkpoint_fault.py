@@ -115,11 +115,6 @@ def test_crash_restart_resumes_deterministically(tmp_path):
         )
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing seed failure (jax.sharding.AxisType missing on the "
-    "pinned jax); ROADMAP: 'Fix 3 pre-existing failures'",
-)
 def test_elastic_restore_resharding(tmp_path):
     """Restore re-device_puts against new shardings (mesh change path)."""
     t = {"w": jnp.arange(64.0).reshape(8, 8)}

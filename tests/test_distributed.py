@@ -7,7 +7,6 @@ import subprocess
 import sys
 from pathlib import Path
 
-import pytest
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
@@ -58,14 +57,6 @@ print("RESULT " + json.dumps(out))
 """
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing seed failure, re-checked after the async-pipeline PR: "
-    "the subprocess dies at mesh construction — jax.sharding.AxisType does "
-    "not exist on the pinned jax (0.4.37; the API landed in 0.6), so the "
-    "shard_map walk path (incl. PR 3's global walk-id threading) is never "
-    "reached; ROADMAP: 'Fix 3 pre-existing failures'",
-)
 def test_distributed_engine_subprocess():
     code = SCRIPT.format(src=SRC)
     proc = subprocess.run(
@@ -124,8 +115,7 @@ def test_distributed_persists_through_shared_pool(tmp_path):
 def test_distributed_single_device_matches_oracle():
     """In-process pin for the distributed sweep (1x1 mesh, one block): the
     wid-carrying routing + counter-based RNG must reproduce the in-memory
-    oracle's walks bitwise — the same identity the multi-rank subprocess
-    test asserts when the pinned jax grows shard_map support."""
+    oracle's walks bitwise."""
     import jax
     import numpy as np
     from jax.sharding import Mesh

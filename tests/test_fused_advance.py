@@ -112,6 +112,19 @@ def test_advance_impl_validated():
         BiBlockEngine(bg, task, advance_impl="mosaic")
 
 
+def test_pallas_refused_off_the_cpu(monkeypatch):
+    """Off the CPU the kernel would need Mosaic, which refuses its 1-D
+    gathers: construction fails instead of timing the interpreter."""
+    import repro.engines.base as base
+
+    bg = partition_into_n_blocks(erdos_renyi(40, 160, seed=0), 2)
+    task = rwnv_task(walks_per_vertex=1, length=4, seed=0)
+    monkeypatch.setattr(base.jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="gather"):
+        BiBlockEngine(bg, task, advance_impl="pallas")
+    BiBlockEngine(bg, task).close()  # the jax lowering is admitted everywhere
+
+
 def test_fused_advance_first_order(small_blocked):
     """DeepWalk (order-1, k_max=1) path through the fused kernel."""
     from repro.core import deepwalk_task

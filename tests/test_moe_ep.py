@@ -8,7 +8,6 @@ import subprocess
 import sys
 from pathlib import Path
 
-import pytest
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
@@ -54,11 +53,6 @@ print("RESULT " + json.dumps(out))
 """
 
 
-@pytest.mark.xfail(
-    strict=False,
-    reason="pre-existing seed failure (jax.sharding.AxisType missing on the "
-    "pinned jax); ROADMAP: 'Fix 3 pre-existing failures'",
-)
 def test_moe_ep_subprocess():
     code = SCRIPT.format(src=SRC)
     proc = subprocess.run(
