@@ -1,0 +1,16 @@
+"""Host-device call: share (%) of the window the engine's thread spends
+padding the walk state, packing the resident pair and uploading both (program
+spans ``advance.pack`` and ``advance.upload``)."""
+
+NAMES = ("advance.pack", "advance.upload")
+
+
+def read(r):
+    win = r.out["window"]
+    spans = getattr(win.stats, "spans", None)
+    if spans is None or r.window_s <= 0:
+        return None
+    seconds = spans.window(win.t_open, win.t_close, spans.thread_of("advance"))
+    if seconds is None:
+        return None
+    return 100.0 * sum(seconds.get(k, 0.0) for k in NAMES) / r.window_s

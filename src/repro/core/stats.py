@@ -11,8 +11,9 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-import time
 from collections import defaultdict
+
+from .spans import SpanRecorder
 
 __all__ = ["DevicePreset", "SSD", "HBM_V5E", "ICI_V5E", "IOStats"]
 
@@ -49,6 +50,8 @@ class IOStats:
         # walk_io is the one counter path hit from multiple writer threads
         # (one per pool shard); everything else stays single-producer
         self._walk_lock = threading.Lock()
+        #: program spans at the layer boundaries (``repro.core.spans``)
+        self.spans = SpanRecorder()
         self.reset()
 
     def reset(self) -> None:
@@ -81,9 +84,15 @@ class IOStats:
         self.sim_block_io_time = 0.0
         self.sim_vertex_io_time = 0.0
         self.sim_ondemand_io_time = 0.0
-        self.exec_time = 0.0  # wall time inside walk updating
-        self.wall_start = time.perf_counter()
+        # wall time of the advance calls: the summed advance.device and
+        # advance.fetch spans (dispatch, device, copy back)
+        self.exec_time = 0.0
         self.per_block_loads = defaultdict(int)
+        self.spans.clear()
+
+    def span(self, name: str, n: int = 0, **kw):
+        """``with stats.span(name, n):`` times a block as a program span."""
+        return self.spans.span(name, n, **kw)
 
     # -- metering ------------------------------------------------------------
     def block_load(self, block_id: int, nbytes: int, *, sequential: bool) -> None:

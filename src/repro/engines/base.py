@@ -18,7 +18,6 @@ Every out-of-core engine owns
 from __future__ import annotations
 
 import dataclasses
-import time
 from functools import partial
 from typing import Callable, Optional, Tuple, Union
 
@@ -122,10 +121,10 @@ class ResidentPair:
             aq = np.ones(1, np.float32)
         return vids, indptr, indices, aj, aq
 
-    def device_args(self):
-        """Pack both slots into the kernel's flat ragged arrays.  Returns
-        ``(args, v_iters)`` — ``v_iters`` is the static binary-search depth
-        for the remap lookup at this padded size."""
+    def pack(self):
+        """Pack both slots into the kernel's flat ragged host arrays.
+        Returns ``(arrays, v_iters)`` — ``v_iters`` is the static
+        binary-search depth for the remap lookup at this padded size."""
         v0, v1 = self.views
         dedupe = v1 is v0
         slots = [0] if dedupe else [0, 1]
@@ -163,18 +162,13 @@ class ResidentPair:
             self.stats.note_resident(nbytes)
         max_cap = max(vc for _, vc, _ in segs)
         v_iters = remap_search_iters(max_cap)
-        args = (
-            jnp.asarray(vids),
-            jnp.asarray(nverts),
-            jnp.asarray(vid_base),
-            jnp.asarray(indptr),
-            jnp.asarray(ptr_base),
-            jnp.asarray(indices),
-            jnp.asarray(ind_base),
-            jnp.asarray(alias_j),
-            jnp.asarray(alias_q),
-        )
-        return args, v_iters
+        arrays = (vids, nverts, vid_base, indptr, ptr_base, indices, ind_base, alias_j, alias_q)
+        return arrays, v_iters
+
+    def device_args(self):
+        """:meth:`pack`, uploaded: ``(device arrays, v_iters)``."""
+        arrays, v_iters = self.pack()
+        return tuple(jnp.asarray(a) for a in arrays), v_iters
 
 
 class EngineBase:
@@ -368,52 +362,63 @@ class EngineBase:
         host batch and alive mask.  ``alive`` masks walks already retired in
         a previous round of the same bucket (mid-advance extensions)."""
         n = len(batch)
-        N = pow2_pad(n)
-        pad = N - n
+        pad = pow2_pad(n) - n
 
         def pad32(x, fill):
-            return jnp.asarray(np.concatenate([x.astype(np.int32), np.full(pad, fill, np.int32)]))
+            return np.concatenate([x.astype(np.int32), np.full(pad, fill, np.int32)])
 
-        prev = pad32(batch.prev, 0)
-        cur = pad32(batch.cur, 0)
-        hop = pad32(batch.hop, 0)
-        wid_dev = pad32(wid, 0)
-        alive_host = np.ones(n, bool) if alive is None else alive
-        alive_dev = jnp.asarray(np.concatenate([alive_host, np.zeros(pad, bool)]))
-        pair_args, v_iters = self.pair.device_args()
-        t0 = time.perf_counter()
         if self.advance_impl == "pallas":
             # construction admits "pallas" only on the CPU backend
             advance = partial(fused_advance_pair, interpret=True)
         else:
             advance = advance_pair
-        out = advance(
-            *pair_args,
-            wid_dev,
-            prev,
-            cur,
-            hop,
-            alive_dev,
-            self._base_key,
-            jnp.int32(self.task.length),
-            jnp.float32(self.task.decay),
-            jnp.float32(getattr(self.task.model, "p", 1.0)),
-            jnp.float32(getattr(self.task.model, "q", 1.0)),
-            order=self.order,
-            k_max=self.k_max,
-            n_iters=self.n_iters,
-            v_iters=v_iters,
-            record=self.record_walks,
-            has_alias=self.has_alias,
-            max_len=int(self.task.length),
-        )
-        prev_f, cur_f, hop_f, alive_f, steps, trace = jax.tree.map(
-            np.asarray, jax.block_until_ready(out)
-        )
-        self.stats.exec_time += time.perf_counter() - t0
+        span = self.stats.span
+        # the advance's parts abut, each starting at the clock reading that
+        # ended the one before
+        with span("advance", n) as call:
+            with span("advance.pack", t0=call.t0) as pack:
+                alive_host = np.ones(n, bool) if alive is None else alive
+                walk_host = (
+                    pad32(wid, 0),
+                    pad32(batch.prev, 0),
+                    pad32(batch.cur, 0),
+                    pad32(batch.hop, 0),
+                    np.concatenate([alive_host, np.zeros(pad, bool)]),
+                )
+                pair_host, v_iters = self.pair.pack()
+            with span("advance.upload", t0=pack.t1) as upload:
+                wid_dev, prev, cur, hop, alive_dev = (jnp.asarray(a) for a in walk_host)
+                pair_args = tuple(jnp.asarray(a) for a in pair_host)
+            # exec_time: from the call to the end of the copy back
+            with span("advance.device", t0=upload.t1) as device:
+                out = advance(
+                    *pair_args,
+                    wid_dev,
+                    prev,
+                    cur,
+                    hop,
+                    alive_dev,
+                    self._base_key,
+                    jnp.int32(self.task.length),
+                    jnp.float32(self.task.decay),
+                    jnp.float32(getattr(self.task.model, "p", 1.0)),
+                    jnp.float32(getattr(self.task.model, "q", 1.0)),
+                    order=self.order,
+                    k_max=self.k_max,
+                    n_iters=self.n_iters,
+                    v_iters=v_iters,
+                    record=self.record_walks,
+                    has_alias=self.has_alias,
+                    max_len=int(self.task.length),
+                )
+                jax.block_until_ready(out)
+            with span("advance.fetch", t0=device.t1) as fetch:
+                prev_f, cur_f, hop_f, alive_f, steps, trace = jax.tree.map(np.asarray, out)
+            if self.record_walks:
+                with span("advance.record", t0=fetch.t1):
+                    self._record_trace(wid, trace[:n])
+        self.stats.exec_time += fetch.t1 - device.t0
         self.stats.steps_sampled += int(steps)
-        if self.record_walks:
-            self._record_trace(wid, trace[:n])
         new_batch = WalkBatch(batch.src, prev_f[:n], cur_f[:n], hop_f[:n])
         return new_batch, alive_f[:n]
 

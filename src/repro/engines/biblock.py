@@ -81,7 +81,8 @@ class BiBlockEngine(EngineBase):
     # skewed storage: persist with min(B(u), B(v)); first-order models never
     # read prev, so they use the traditional B(cur) association (§7.8)
     def _persist(self, batch: WalkBatch, wid: np.ndarray) -> None:
-        push_by_block_assignment(self.pool, self.bg.block_starts, self.order, batch, wid)
+        with self.stats.span("pool.push", len(batch)):
+            push_by_block_assignment(self.pool, self.bg.block_starts, self.order, batch, wid)
 
     #: modelled in-memory cost per sampled step (feeds the LR exec component)
     STEP_COST = 2.0e-8
@@ -246,26 +247,29 @@ class BiBlockEngine(EngineBase):
             cost += ext_cost
             cost += self.STEP_COST * (self.stats.steps_sampled - steps_before)
             self.loader.observe(i, eta, cost, decision)
-            bucket, bwid = self._retire(bucket, bwid, alive)
-            if len(bucket) == 0:
-                continue
-            # Alg. 2 routing
-            pre_blk = block_of(self.bg.block_starts, bucket.prev)
-            cur_blk = block_of(self.bg.block_starts, bucket.cur)
-            extend = (
-                (cur_blk > i) & (pre_blk == b)
-                if self.bucket_extending
-                else np.zeros(len(bucket), bool)
-            )
+            with self.stats.span("slot.route", len(bucket)):
+                bucket, bwid = self._retire(bucket, bwid, alive)
+                if len(bucket) == 0:
+                    continue
+                # Alg. 2 routing
+                pre_blk = block_of(self.bg.block_starts, bucket.prev)
+                cur_blk = block_of(self.bg.block_starts, bucket.cur)
+                extend = (
+                    (cur_blk > i) & (pre_blk == b)
+                    if self.bucket_extending
+                    else np.zeros(len(bucket), bool)
+                )
+                stay = bucket.select(~extend), bwid[~extend]
             # persist the non-extending walks with min-rule
-            self._persist(bucket.select(~extend), bwid[~extend])
+            self._persist(*stay)
             if extend.any():
-                ext_batch = bucket.select(extend)
-                ext_wid = bwid[extend]
-                ext_blk = cur_blk[extend]
-                for nb in np.unique(ext_blk):
-                    m = ext_blk == nb
-                    cursor.add(int(nb), ext_batch.select(m), ext_wid[m])
+                with self.stats.span("slot.route", int(extend.sum())):
+                    ext_batch = bucket.select(extend)
+                    ext_wid = bwid[extend]
+                    ext_blk = cur_blk[extend]
+                    for nb in np.unique(ext_blk):
+                        m = ext_blk == nb
+                        cursor.add(int(nb), ext_batch.select(m), ext_wid[m])
 
     def _run_slot_first_order(self, b: int, pipe: BucketPipeline) -> None:
         """§7.8: first-order walks need only the current block; iteration
@@ -284,5 +288,6 @@ class BiBlockEngine(EngineBase):
         cost += ext_cost
         cost += self.STEP_COST * (self.stats.steps_sampled - steps_before)
         self.loader.observe(b, eta, cost, decision)
-        batch, wid = self._retire(batch, wid, alive)
+        with self.stats.span("slot.route", len(batch)):
+            batch, wid = self._retire(batch, wid, alive)
         self._persist(batch, wid)

@@ -181,7 +181,8 @@ class BucketPipeline:
         starts = self.block_starts
 
         def split(batch: WalkBatch, wid: np.ndarray):
-            return split_into_buckets(starts, batch, b, wid)
+            with self.stats.span("buckets.split", len(batch)):
+                return split_into_buckets(starts, batch, b, wid)
 
         return split
 
@@ -191,16 +192,20 @@ class BucketPipeline:
         :class:`BucketCursor` for second-order slots, a ``(batch, wid)``
         pair for first-order ones."""
         fut = self._preloads.pop(b, None)
-        if fut is None:
-            self.stats.note_stall_slot()
-            batch, wid = self.pool.load(b)
-            return self._package(b, batch, wid, pre=None)
-        payload, _n_walks, n_spilled = fut.result()
-        self.stats.note_overlapped(n_spilled * WALK_BYTES)
-        if self.pool.counts[b] > 0:  # pushed after the preload point
-            batch, wid = self.pool.load(b)
-        else:
-            batch, wid = WalkBatch.empty(), np.zeros(0, np.int64)
+        with self.stats.span("pool.acquire") as acquire:
+            if fut is None:
+                self.stats.note_stall_slot()
+                payload = None
+                batch, wid = self.pool.load(b)
+            else:
+                payload, n_pre, n_spilled = fut.result()
+                self.stats.note_overlapped(n_spilled * WALK_BYTES)
+                acquire.n = n_pre
+                if self.pool.counts[b] > 0:  # pushed after the preload point
+                    batch, wid = self.pool.load(b)
+                else:
+                    batch, wid = WalkBatch.empty(), np.zeros(0, np.int64)
+            acquire.n += len(batch)
         return self._package(b, batch, wid, pre=payload)
 
     def _package(self, b: int, batch: WalkBatch, wid: np.ndarray, pre):
@@ -215,7 +220,9 @@ class BucketPipeline:
             for i, (bb, ww) in pre.items():
                 cursor.add(i, bb, ww)
         if len(batch):
-            for i, (bb, ww) in split_into_buckets(self.block_starts, batch, b, wid).items():
+            with self.stats.span("buckets.split", len(batch)):
+                buckets = split_into_buckets(self.block_starts, batch, b, wid)
+            for i, (bb, ww) in buckets.items():
                 cursor.add(i, bb, ww)
         return cursor
 

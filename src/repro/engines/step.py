@@ -132,6 +132,7 @@ def pair_advance_impl(
     trace0 = jnp.full((N, max_len + 2) if record else (1, 1), -1, dtype=jnp.int32)
     iota = jnp.arange(N)
 
+    @jax.named_scope("advance.locate")
     def locate(v):
         """Resolve global vertex -> (slot, compact row, found) via the remap."""
         r0, found0 = lower_bound_rows(
@@ -202,22 +203,24 @@ def pair_advance_impl(
             z_ = jnp.where(take, zk, z_)
             return z_, accepted_ | take
 
-        z, _ = jax.lax.fori_loop(0, k_max, propose, (cur_, ~movable))
+        with jax.named_scope("advance.propose"):
+            z, _ = jax.lax.fori_loop(0, k_max, propose, (cur_, ~movable))
 
-        # ---- commit ----------------------------------------------------------
-        u_term = rng.uniform1(*rng.fold_in(kw0, kw1, k_max))
-        new_hop = hop_ + movable.astype(jnp.int32)
-        new_prev = jnp.where(movable, cur_, prev_)
-        new_cur = jnp.where(movable, z, cur_)
-        finished = movable & (new_hop >= length)
-        stopped = movable & (u_term >= decay)
-        new_alive = alive_ & ~dead & ~finished & ~stopped
-        new_slot, new_row, new_found = locate(new_cur)
-        new_resident = new_alive & new_found
-        if record:
-            cols = jnp.where(movable, jnp.clip(new_hop, 0, max_len), max_len + 1)
-            trace_ = trace_.at[iota, cols].set(new_cur)
-        steps_ = steps_ + movable.astype(jnp.int32).sum()
+        # ---- commit (the remap search of the new cur nests in its scope) -----
+        with jax.named_scope("advance.hop"):
+            u_term = rng.uniform1(*rng.fold_in(kw0, kw1, k_max))
+            new_hop = hop_ + movable.astype(jnp.int32)
+            new_prev = jnp.where(movable, cur_, prev_)
+            new_cur = jnp.where(movable, z, cur_)
+            finished = movable & (new_hop >= length)
+            stopped = movable & (u_term >= decay)
+            new_alive = alive_ & ~dead & ~finished & ~stopped
+            new_slot, new_row, new_found = locate(new_cur)
+            new_resident = new_alive & new_found
+            if record:
+                cols = jnp.where(movable, jnp.clip(new_hop, 0, max_len), max_len + 1)
+                trace_ = trace_.at[iota, cols].set(new_cur)
+            steps_ = steps_ + movable.astype(jnp.int32).sum()
         return (
             new_prev,
             new_cur,

@@ -52,7 +52,6 @@ accounting (the paper's tables) is untouched.
 from __future__ import annotations
 
 import threading
-import time
 from collections import OrderedDict
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Dict, Optional
@@ -108,11 +107,6 @@ class BlockStore:
         self.partial_prefetch_hits = 0
         self.partial_builds = 0
         self.pinned_hits = 0
-        #: wall time get() spent materialising on the calling thread — the
-        #: quantity prefetch removes from the critical path
-        self.sync_materialize_time = 0.0
-        #: wall time get() spent waiting on a not-yet-finished prefetch
-        self.prefetch_wait_time = 0.0
 
     # -- internals ------------------------------------------------------------
     def _materialize(self, b: int) -> ResidentBlock:
@@ -150,7 +144,11 @@ class BlockStore:
                 max_workers=1,
                 thread_name_prefix="blockstore-prefetch",
             )
-        return self._executor.submit(fn, *args)
+        return self._executor.submit(self._job, fn, *args)
+
+    def _job(self, fn, *args):
+        with self.stats.span("blocks.build"):
+            return fn(*args)
 
     # -- the engine-facing API -------------------------------------------------
     def schedule(self, ops) -> None:
@@ -166,19 +164,22 @@ class BlockStore:
         planner sees one plan per block instead of one per request.  Never
         charges; a no-op when prefetch is disabled.
         """
-        partials: Dict[int, list] = {}
-        for op in ops:
-            if op[0] == "full":
-                self.prefetch(op[1])
-            elif op[0] == "partial":
-                partials.setdefault(int(op[1]), []).append(
-                    np.asarray(op[2], dtype=np.int64)
-                )
-            else:
-                raise ValueError(f"unknown prefetch op {op[0]!r}; have full, partial")
-        for b, sets in partials.items():
-            vs = sets[0] if len(sets) == 1 else np.unique(np.concatenate(sets))
-            self.prefetch_partial(b, vs)
+        with self.stats.span("blocks.schedule") as sp:
+            partials: Dict[int, list] = {}
+            for op in ops:
+                if op[0] == "full":
+                    sp.n += int(self.bg.block_nverts[int(op[1])])
+                    self.prefetch(op[1])
+                elif op[0] == "partial":
+                    partials.setdefault(int(op[1]), []).append(
+                        np.asarray(op[2], dtype=np.int64)
+                    )
+                else:
+                    raise ValueError(f"unknown prefetch op {op[0]!r}; have full, partial")
+            for b, sets in partials.items():
+                vs = sets[0] if len(sets) == 1 else np.unique(np.concatenate(sets))
+                sp.n += vs.size
+                self.prefetch_partial(b, vs)
 
     # -- hot-set policy (serving layer) ----------------------------------------
     def pin(self, blocks) -> None:
@@ -279,15 +280,13 @@ class BlockStore:
             # first touch: materialise (joining any in-flight prefetch),
             # pay the normal block_load charge, and keep the copy pinned
             if fut is not None:
-                t0 = time.perf_counter()
-                blk = fut.result()
-                self.prefetch_wait_time += time.perf_counter() - t0
+                with self.stats.span("blocks.prefetch_wait"):
+                    blk = fut.result()
                 self.prefetch_hits += 1
                 self.stats.note_overlapped(blk.nbytes_full())
             else:
-                t0 = time.perf_counter()
-                blk = self._materialize(b)
-                self.sync_materialize_time += time.perf_counter() - t0
+                with self.stats.span("blocks.materialize"):
+                    blk = self._materialize(b)
                 self.demand_loads += 1
             with self._lock:
                 if b in self._pinned:
@@ -298,18 +297,16 @@ class BlockStore:
                 self.stats.block_load(b, blk.nbytes_full(), sequential=sequential)
             return blk
         if fut is not None:
-            t0 = time.perf_counter()
-            blk = fut.result()
-            self.prefetch_wait_time += time.perf_counter() - t0
+            with self.stats.span("blocks.prefetch_wait"):
+                blk = fut.result()
             self.prefetch_hits += 1
             # the materialisation ran off the critical path — measure the win
             self.stats.note_overlapped(blk.nbytes_full())
         elif blk is not None:
             self.cache_hits += 1
         else:
-            t0 = time.perf_counter()
-            blk = self._materialize(b)
-            self.sync_materialize_time += time.perf_counter() - t0
+            with self.stats.span("blocks.materialize"):
+                blk = self._materialize(b)
             self.demand_loads += 1
         self._insert(b, blk)
         if charge:
@@ -319,7 +316,8 @@ class BlockStore:
     def get_view(self, b: int, *, sequential: bool = True, charge: bool = True) -> BlockView:
         """Full :class:`BlockView` of block ``b`` (same charging as
         :meth:`get`)."""
-        return BlockView.from_resident(self.get(b, sequential=sequential, charge=charge))
+        with self.stats.span("blocks.get_view", int(self.bg.block_nverts[int(b)])):
+            return BlockView.from_resident(self.get(b, sequential=sequential, charge=charge))
 
     def partial_view(self, b: int, vertices: np.ndarray) -> BlockView:
         """Activated view of block ``b`` over exactly the unique
@@ -334,29 +332,28 @@ class BlockStore:
         """
         b = int(b)
         vs = np.unique(np.asarray(vertices, dtype=np.int64))
-        # gauge the plan over the full requested set (prefetch-invariant)
-        self._note_ondemand_plan(vs)
-        base = None
-        with self._lock:
-            fut = self._pfutures.pop(b, None)
-        if fut is not None:
-            t0 = time.perf_counter()
-            base = fut.result()
-            self.prefetch_wait_time += time.perf_counter() - t0
-        if base is not None:
-            in_req = np.isin(base.vids, vs)
-            if in_req.all():
-                self.partial_prefetch_hits += 1
-                self.stats.note_overlapped(self.bg.activated_load_bytes(base.vids))
-                missing = vs[~base.has_vertices(vs)]
-                if missing.size:
-                    base = self._extend(base, missing)
-                return base
-        t0 = time.perf_counter()
-        view = self._build_partial(b, vs)
-        self.sync_materialize_time += time.perf_counter() - t0
-        self.partial_builds += 1
-        return view
+        with self.stats.span("blocks.partial_view", vs.size):
+            # gauge the plan over the full requested set (prefetch-invariant)
+            self._note_ondemand_plan(vs)
+            base = None
+            with self._lock:
+                fut = self._pfutures.pop(b, None)
+            if fut is not None:
+                with self.stats.span("blocks.prefetch_wait"):
+                    base = fut.result()
+            if base is not None:
+                in_req = np.isin(base.vids, vs)
+                if in_req.all():
+                    self.partial_prefetch_hits += 1
+                    self.stats.note_overlapped(self.bg.activated_load_bytes(base.vids))
+                    missing = vs[~base.has_vertices(vs)]
+                    if missing.size:
+                        base = self._extend(base, missing)
+                    return base
+            with self.stats.span("blocks.materialize"):
+                view = self._build_partial(b, vs)
+            self.partial_builds += 1
+            return view
 
     def _extend(self, view: BlockView, vertices: np.ndarray) -> BlockView:
         extra = self._build_partial(view.block_id, vertices)
@@ -367,16 +364,18 @@ class BlockStore:
         an activated ``view`` (never charges bytes; the engine accounts the
         gather as on-demand vertex I/O).  Meters the read-planner gauges
         for the gathered set."""
-        self._note_ondemand_plan(np.asarray(vertices, dtype=np.int64))
-        return self._extend(view, vertices)
+        with self.stats.span("blocks.extend_view", len(vertices)):
+            self._note_ondemand_plan(np.asarray(vertices, dtype=np.int64))
+            return self._extend(view, vertices)
 
     def gather_view(self, vertices: np.ndarray) -> BlockView:
         """Cross-block activated view over arbitrary vertices (never
         charges bytes; the engine accounts the per-vertex fetches).  Meters
         the read-planner gauges for the gathered set."""
-        self._note_ondemand_plan(np.asarray(vertices, dtype=np.int64))
-        with self._mat_lock:
-            return self.bg.gather_view(vertices)
+        with self.stats.span("blocks.gather_view", len(vertices)):
+            self._note_ondemand_plan(np.asarray(vertices, dtype=np.int64))
+            with self._mat_lock:
+                return self.bg.gather_view(vertices)
 
     def counters(self) -> dict:
         return {
@@ -389,8 +388,11 @@ class BlockStore:
             "partial_builds": self.partial_builds,
             "pinned_blocks": len(self._pinned),
             "pinned_hits": self.pinned_hits,
-            "sync_materialize_time": self.sync_materialize_time,
-            "prefetch_wait_time": self.prefetch_wait_time,
+            # main-thread span totals: materialising on the calling thread
+            # (what prefetch removes from the critical path), and waiting on
+            # a prefetch that had not finished
+            "sync_materialize_time": self.stats.spans.seconds("blocks.materialize"),
+            "prefetch_wait_time": self.stats.spans.seconds("blocks.prefetch_wait"),
         }
 
     def close(self) -> None:

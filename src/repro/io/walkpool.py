@@ -355,9 +355,6 @@ class AsyncWalkPool:
         self.min_hop = base.min_hop.copy()
         self.tickets_issued = 0
         self.applied_ticket = 0
-        #: pool-local high-water copy of ``IOStats.writer_queue_peak`` for
-        #: stats-less construction; both update from the same _enqueue line
-        self.queue_peak = 0
         self._q: deque = deque()
         self._cv = threading.Condition()
         self._error: Optional[BaseException] = None
@@ -385,7 +382,11 @@ class AsyncWalkPool:
                     return  # closed and fully drained
                 job = self._q.popleft()
                 self._cv.notify_all()  # wake producers blocked on a full queue
-            self._apply(job)
+            if self.stats is None:
+                self._apply(job)
+            else:
+                with self.stats.span("pool.apply"):
+                    self._apply(job)
 
     def _apply(self, job) -> None:
         kind, fut = job[0], job[-1]
@@ -427,7 +428,6 @@ class AsyncWalkPool:
             if self._closed:
                 raise RuntimeError("AsyncWalkPool is closed")
             self._q.append(job)
-            self.queue_peak = max(self.queue_peak, len(self._q))
             if self.stats is not None:
                 self.stats.note_writer_queue(len(self._q))
             self._cv.notify_all()

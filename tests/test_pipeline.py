@@ -249,7 +249,7 @@ def test_async_pool_preserves_serial_order_and_accounting():
     # sequencing bookkeeping: every ticket applied, in order
     pool.barrier()
     assert pool.tickets_issued == 6 and pool.applied_ticket == 6
-    assert pool.queue_peak >= 1 and stats.writer_queue_peak == pool.queue_peak
+    assert stats.writer_queue_peak >= 1
     # spill accounting matches the serial pool stepped through the same op
     # sequence (same thresholds crossed at the same points)
     assert stats.walk_bytes_written == serial_stats.walk_bytes_written
