@@ -245,7 +245,7 @@ class DistributedWalkEngine:
                 # --- advance on the resident view pair ----------------------
                 own_vids = make_vids(own.start, own.nverts)
                 partner_vids = make_vids(partner.start, partner.nverts)
-                nprev, ncur, nhop, nalive, _, _ = pair_advance_impl(
+                nprev, ncur, nhop, nalive, _, _, _ = pair_advance_impl(
                     jnp.concatenate([own_vids, partner_vids]),
                     jnp.stack([own.nverts, partner.nverts]),
                     jnp.array([0, mv], jnp.int32),

@@ -80,6 +80,9 @@ class IOStats:
         self.time_slots = 0
         self.supersteps = 0
         self.steps_sampled = 0
+        # lanes the advance loop ran, summed over its iterations (each
+        # call's count is also the n of its advance.fetch span)
+        self.advance_lane_iters = 0
         self.bucket_executions = 0
         self.sim_block_io_time = 0.0
         self.sim_vertex_io_time = 0.0
@@ -280,6 +283,7 @@ class IOStats:
             "time_slots": self.time_slots,
             "supersteps": self.supersteps,
             "steps_sampled": self.steps_sampled,
+            "advance_lane_iters": self.advance_lane_iters,
             "bucket_executions": self.bucket_executions,
             "sim_block_io_time": self.sim_block_io_time,
             "sim_vertex_io_time": self.sim_vertex_io_time,

@@ -413,12 +413,14 @@ class EngineBase:
                 )
                 jax.block_until_ready(out)
             with span("advance.fetch", t0=device.t1) as fetch:
-                prev_f, cur_f, hop_f, alive_f, steps, trace = jax.tree.map(np.asarray, out)
+                prev_f, cur_f, hop_f, alive_f, steps, trace, lane_iters = jax.tree.map(np.asarray, out)
+                fetch.n = int(lane_iters)
             if self.record_walks:
                 with span("advance.record", t0=fetch.t1):
                     self._record_trace(wid, trace[:n])
         self.stats.exec_time += fetch.t1 - device.t0
         self.stats.steps_sampled += int(steps)
+        self.stats.advance_lane_iters += fetch.n
         new_batch = WalkBatch(batch.src, prev_f[:n], cur_f[:n], hop_f[:n])
         return new_batch, alive_f[:n]
 

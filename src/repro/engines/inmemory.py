@@ -101,7 +101,7 @@ class InMemoryWalker:
             has_alias=has_alias,
             max_len=int(task.length),
         )
-        prev_f, cur_f, hop_f, alive_f, steps, trace = jax.tree.map(
+        prev_f, cur_f, hop_f, alive_f, steps, trace, _ = jax.tree.map(
             np.asarray, jax.block_until_ready(out)
         )
         stats.exec_time = time.perf_counter() - t0

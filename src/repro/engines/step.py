@@ -36,6 +36,7 @@ Two properties distinguish this implementation from a textbook step loop:
 from __future__ import annotations
 
 from functools import partial
+from typing import Tuple
 
 import numpy as np
 
@@ -51,10 +52,14 @@ __all__ = [
     "pair_advance_impl",
     "pow2_pad",
     "remap_search_iters",
+    "stage_widths",
 ]
 
 #: vids padding value — sorts after every real vertex id
 VID_PAD = jnp.iinfo(jnp.int32).max
+
+#: narrowest loop width of the staged advance
+STAGE_FLOOR = 256
 
 
 def remap_search_iters(n: int) -> int:
@@ -88,6 +93,17 @@ def lower_bound_rows(flat, lo, hi, z, *, n_iters: int):
     return lo_f, (lo_f < hi0) & (flat[pos] == z)
 
 
+def stage_widths(n: int) -> Tuple[int, ...]:
+    """Loop widths of the staged advance for ``n`` lanes: ``n``, then
+    ``max(256, n/4)`` and ``max(256, n/32)``, each kept only where it is
+    narrower than the stage before (so ``n <= 256`` is one stage)."""
+    widths = [n]
+    for w in (max(STAGE_FLOOR, n // 4), max(STAGE_FLOOR, n // 32)):
+        if w < widths[-1]:
+            widths.append(w)
+    return tuple(widths)
+
+
 def pair_advance_impl(
     vids,        # [SV] i32 — both slots' sorted global vertex ids, concatenated
     nverts,      # [2] i32  — valid vids per slot
@@ -118,9 +134,16 @@ def pair_advance_impl(
     max_len: int,
 ):
     """Advance every walk until it leaves the resident view pair or
-    terminates.  Returns ``(prev, cur, hop, alive, steps_taken, trace)``
-    where ``trace[n, h]`` is the vertex walk n reached at hop h during this
-    call (-1 = no move).
+    terminates.  Returns ``(prev, cur, hop, alive, steps_taken, trace,
+    lane_iters)`` where ``trace[n, h]`` is the vertex walk n reached at hop
+    h during this call (-1 = no move) and ``lane_iters`` the lanes the loop
+    ran, summed over its iterations.
+
+    The hop loop runs in stages of :func:`stage_widths`: a stage leaves once
+    its resident walks fit the next, narrower width, and the next stage
+    carries only those walks.  A walk that is not resident never moves
+    again within the call, and every draw is keyed by (walk id, hop, round),
+    so the staging changes no output but ``lane_iters``.
     """
     N = prev.shape[0]
     max_bias = jnp.maximum(1.0, jnp.maximum(1.0 / p, 1.0 / q))
@@ -129,23 +152,23 @@ def pair_advance_impl(
     # same primitive the fused Pallas kernel lowers under Mosaic
     kwid = rng.fold_in(*rng.key_halves(key), wid)
     # one spare "dump" column (max_len+1) absorbs writes of frozen walks
-    trace0 = jnp.full((N, max_len + 2) if record else (1, 1), -1, dtype=jnp.int32)
-    iota = jnp.arange(N)
+    trace = jnp.full((N, max_len + 2) if record else (1, 1), -1, dtype=jnp.int32)
 
     @jax.named_scope("advance.locate")
     def locate(v):
         """Resolve global vertex -> (slot, compact row, found) via the remap."""
+        W = v.shape[0]
         r0, found0 = lower_bound_rows(
             vids,
-            jnp.full((N,), vid_base[0]),
-            jnp.full((N,), vid_base[0] + nverts[0]),
+            jnp.full((W,), vid_base[0]),
+            jnp.full((W,), vid_base[0] + nverts[0]),
             v,
             n_iters=v_iters,
         )
         r1, found1 = lower_bound_rows(
             vids,
-            jnp.full((N,), vid_base[1]),
-            jnp.full((N,), vid_base[1] + nverts[1]),
+            jnp.full((W,), vid_base[1]),
+            jnp.full((W,), vid_base[1] + nverts[1]),
             v,
             n_iters=v_iters,
         )
@@ -154,14 +177,13 @@ def pair_advance_impl(
         row = jnp.clip(row, 0, None)
         return slot, row, found0 | found1
 
-    def cond(state):
-        _, _, _, _, resident, _, _, _, _, it = state
-        return jnp.any(resident) & (it <= max_len)
-
+    # a stage's carry: the lanes (walk state, where the walk's cur sits, its
+    # RNG stream, its row ``orig`` in the call's batch), then the scalars
     def body(state):
-        prev_, cur_, hop_, alive_, resident, slot, row, steps_, trace_, it = state
+        lanes, steps_, trace_, it = state
+        prev_, cur_, hop_, alive_, resident, slot, row, kwid0, kwid1, orig = lanes
         # counter-based keys: one stream per (walk id, hop)
-        kw0, kw1 = rng.fold_in(*kwid, hop_)
+        kw0, kw1 = rng.fold_in(kwid0, kwid1, hop_)
 
         movable = resident  # alive & cur has a row in the pair
         # (slot, row) for cur_ is carried from the previous iteration's
@@ -197,7 +219,7 @@ def pair_advance_impl(
                 acc_p = bias / max_bias
                 acc_p = jnp.where(hop_ == 0, 1.0, acc_p)  # first step: 1st-order
             else:
-                acc_p = jnp.ones((N,), jnp.float32)
+                acc_p = jnp.ones(zk.shape, jnp.float32)
             last = kk == k_max - 1
             take = (~accepted_) & movable & ((u123[2] < acc_p) | last)
             z_ = jnp.where(take, zk, z_)
@@ -218,10 +240,11 @@ def pair_advance_impl(
             new_slot, new_row, new_found = locate(new_cur)
             new_resident = new_alive & new_found
             if record:
+                # fill lanes of a compacted stage carry orig = N: dropped
                 cols = jnp.where(movable, jnp.clip(new_hop, 0, max_len), max_len + 1)
-                trace_ = trace_.at[iota, cols].set(new_cur)
+                trace_ = trace_.at[orig, cols].set(new_cur, mode="drop")
             steps_ = steps_ + movable.astype(jnp.int32).sum()
-        return (
+        lanes = (
             new_prev,
             new_cur,
             new_hop,
@@ -229,29 +252,54 @@ def pair_advance_impl(
             new_resident,
             new_slot,
             new_row,
-            steps_,
-            trace_,
-            it + 1,
+            kwid0,
+            kwid1,
+            orig,
         )
+        return lanes, steps_, trace_, it + 1
+
+    @jax.named_scope("advance.compact")
+    def compact(lanes, width):
+        """The resident lanes of a stage, packed into ``width`` lanes.  The
+        fill lanes are dead, so never resident, and write back nowhere."""
+        resident = lanes[4]
+        W = resident.shape[0]
+        (sel,) = jnp.nonzero(resident, size=width, fill_value=W)
+        kept = sel < W
+        packed = [x[jnp.minimum(sel, W - 1)] for x in lanes]
+        packed[3] = packed[3] & kept
+        packed[4] = packed[4] & kept
+        packed[9] = jnp.where(kept, packed[9], N)
+        return tuple(packed)
 
     slot0, row0, found0 = locate(cur)
-    resident0 = alive & found0
-    init = (
-        prev,
-        cur,
-        hop,
-        alive,
-        resident0,
-        slot0,
-        row0,
-        jnp.zeros((), jnp.int32),
-        trace0,
-        jnp.zeros((), jnp.int32),
-    )
-    prev_f, cur_f, hop_f, alive_f, _, _, _, steps, trace, _ = jax.lax.while_loop(cond, body, init)
+    lanes = (prev, cur, hop, alive, alive & found0, slot0, row0, *kwid, jnp.arange(N))
+    walks = lanes[:4]  # prev, cur, hop, alive of every lane, by batch row
+    steps = jnp.zeros((), jnp.int32)
+    it = jnp.zeros((), jnp.int32)
+    lane_iters = jnp.zeros((), jnp.int32)
+    widths = stage_widths(N)
+    for k, width in enumerate(widths):
+        if k > 0:
+            lanes = compact(lanes, width)
+        w_next = widths[k + 1] if k + 1 < len(widths) else 0
+
+        def cond(state, w_next=w_next):
+            lanes_, _, _, it_ = state
+            # more resident walks than the next stage holds (any, in the last)
+            return (jnp.sum(lanes_[4], dtype=jnp.int32) > w_next) & (it_ <= max_len)
+
+        it_in = it
+        lanes, steps, trace, it = jax.lax.while_loop(cond, body, (lanes, steps, trace, it))
+        lane_iters = lane_iters + (it - it_in) * width
+        if k == 0:
+            walks = lanes[:4]  # the first stage runs every lane in batch order
+        else:
+            walks = tuple(w.at[lanes[9]].set(x, mode="drop") for w, x in zip(walks, lanes[:4]))
+    prev_f, cur_f, hop_f, alive_f = walks
     if record:
         trace = trace[:, : max_len + 1]
-    return prev_f, cur_f, hop_f, alive_f, steps, trace
+    return prev_f, cur_f, hop_f, alive_f, steps, trace, lane_iters
 
 
 #: jitted entry point (host engines); the raw impl is reused inside shard_map

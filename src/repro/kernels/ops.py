@@ -70,7 +70,7 @@ def node2vec_step(
 ):
     """One walk hop for a batch over a resident pair. Returns (z, moved)."""
     if use_kernel:
-        _, cur_f, hop_f, _, _, _ = fused_advance_pair(
+        _, cur_f, hop_f, _, _, _, _ = fused_advance_pair(
             vids,
             nverts,
             vid_base,

@@ -97,6 +97,7 @@ def pair_advance_kernel(
     hop_out,       # [T] i32
     alive_out,     # [T] i32
     trace_out,     # [T, max_len+2] i32 ([T, 1] if not record)
+    iters_out,     # [T] i32    the tile's loop iterations, on every lane
     *,
     order: int,
     k_max: int,
@@ -220,13 +221,14 @@ def pair_advance_kernel(
     slot0, row0, found0 = locate(cur0)
     resident0 = alive0 & found0
     init = (prev0, cur0, hop0, alive0, resident0, slot0, row0, trace0, jnp.int32(0))
-    prev_f, cur_f, hop_f, alive_f, _, _, _, trace_f, _ = jax.lax.while_loop(cond, body, init)
+    prev_f, cur_f, hop_f, alive_f, _, _, _, trace_f, it_f = jax.lax.while_loop(cond, body, init)
 
     prev_out[...] = prev_f
     cur_out[...] = cur_f
     hop_out[...] = hop_f
     alive_out[...] = alive_f.astype(jnp.int32)
     trace_out[...] = trace_f
+    iters_out[...] = jnp.full((T,), it_f, jnp.int32)
 
 
 @functools.partial(
@@ -279,7 +281,10 @@ def fused_advance_pair(
     """Drop-in fused replacement for :func:`repro.engines.step.advance_pair`.
 
     Identical argument list and return contract
-    ``(prev, cur, hop, alive, steps, trace)``; bit-identical outputs.  The
+    ``(prev, cur, hop, alive, steps, trace, lane_iters)``; bit-identical
+    walk outputs.  ``lane_iters`` counts this kernel's own work: every tile
+    runs all its lanes until its last walk leaves, so it is the tile width
+    times the tile's iterations, summed over tiles.  The
     extra statics select the Pallas lowering: ``interpret`` (the Pallas
     interpreter vs Mosaic, which refuses this kernel), ``walk_tile`` (grid chunk), and
     ``max_hops`` (loop bound — ``None`` means the full ``max_len + 1``
@@ -322,7 +327,7 @@ def fused_advance_pair(
         max_len=max_len,
         max_hops=hops,
     )
-    prev_f, cur_f, hop_f, alive_f, trace = pl.pallas_call(
+    prev_f, cur_f, hop_f, alive_f, trace, iters = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -344,13 +349,14 @@ def fused_advance_pair(
             walk_spec,
             walk_spec,
         ],
-        out_specs=[walk_spec, walk_spec, walk_spec, walk_spec, trace_spec],
+        out_specs=[walk_spec, walk_spec, walk_spec, walk_spec, trace_spec, walk_spec],
         out_shape=[
             jax.ShapeDtypeStruct((N,), jnp.int32),
             jax.ShapeDtypeStruct((N,), jnp.int32),
             jax.ShapeDtypeStruct((N,), jnp.int32),
             jax.ShapeDtypeStruct((N,), jnp.int32),
             jax.ShapeDtypeStruct((N, TC), jnp.int32),
+            jax.ShapeDtypeStruct((N,), jnp.int32),
         ],
         interpret=interpret,
     )(
@@ -374,8 +380,10 @@ def fused_advance_pair(
     )
     # hop only advances on committed moves, so the delta *is* the step count
     steps = jnp.sum(hop_f - hop_in).astype(jnp.int32)
+    # each lane holds its tile's iterations: the padded lanes ran too
+    lane_iters = jnp.sum(iters).astype(jnp.int32)
     if record:
         trace = trace[:n0, : max_len + 1]
     else:
         trace = jnp.full((1, 1), -1, jnp.int32)
-    return prev_f[:n0], cur_f[:n0], hop_f[:n0], alive_f[:n0] > 0, steps, trace
+    return prev_f[:n0], cur_f[:n0], hop_f[:n0], alive_f[:n0] > 0, steps, trace, lane_iters

@@ -3,7 +3,8 @@
 The fused advance kernel is validated two independent ways: single-hop
 against the dense ``node2vec_step_ref`` oracle fed explicit counter-keyed
 uniforms, and multi-hop against the plain jitted ``pair_advance_impl`` —
-both bitwise.
+both bitwise.  The staged jitted advance is checked against itself run
+one stage wide, on the same walks cut into chunks.
 """
 
 import numpy as np
@@ -15,7 +16,7 @@ from repro.testing import given, settings, st
 from repro.core import erdos_renyi, partition_into_n_blocks
 from repro.core.graph import BlockView
 from repro.engines.base import ResidentPair
-from repro.engines.step import advance_pair
+from repro.engines.step import advance_pair, stage_widths
 from repro.kernels import (
     alias_step,
     bucket_hist_kernel,
@@ -113,8 +114,117 @@ def test_fused_multi_hop_matches_jax_impl():
     ref = advance_pair(*pair, wid, prev, cur, hop, alive, key, *sc, **kw)
     fus = fused_advance_pair(*pair, wid, prev, cur, hop, alive, key, *sc, **kw,
                              interpret=True, walk_tile=256)
-    for a, b in zip(ref, fus):
+    # the walk outputs; the seventh, lane_iters, counts each kernel's own work
+    for a, b in zip(ref[:6], fus[:6]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---------------------------------------------------------------------------
+# the staged advance: compacting resident lanes changes no walk
+# ---------------------------------------------------------------------------
+
+def _batch(bg, n, *, blocks, seed, alive_share=0.95):
+    """``n`` walks with cur and prev in ``blocks`` (a range of block ids)."""
+    r = np.random.default_rng(seed)
+    lo, hi = bg.block_starts[blocks[0]], bg.block_starts[blocks[-1] + 1]
+    cur = r.integers(lo, hi, n).astype(np.int32)
+    prev = r.integers(lo, hi, n).astype(np.int32)
+    hop = r.integers(0, 3, n).astype(np.int32)
+    # retired walks first: the last lane, which the fill lanes of a compacted
+    # stage copy, is a live walk
+    alive = np.sort(r.random(n) < alive_share)
+    wid = r.permutation(1 << 20)[:n].astype(np.int32)
+    return tuple(jnp.asarray(x) for x in (wid, prev, cur, hop, alive))
+
+
+def _chunked(pair, walks, key, sc, kw, chunk=256):
+    """The reference: each chunk of ``chunk`` walks alone through
+    ``advance_pair``, a single stage, joined back into one batch."""
+    assert stage_widths(chunk) == (chunk,)
+    n = walks[0].shape[0]
+    outs = [
+        advance_pair(*pair, *(w[s : s + chunk] for w in walks), key, *sc, **kw)
+        for s in range(0, n, chunk)
+    ]
+    joined = [np.concatenate([np.asarray(o[i]) for o in outs]) for i in range(4)]
+    steps = sum(int(o[4]) for o in outs)
+    trace = np.concatenate([np.asarray(o[5]) for o in outs]) if kw["record"] else None
+    return joined, steps, trace
+
+
+#: (walks, order, alias, record, alive share, blocks in the graph): the pair
+#: is blocks 0 and 1; at three blocks it holds two thirds of the graph
+STAGED_CASES = {
+    "n4096-o2-record": (4096, 2, False, True, 0.95, 4),
+    "n4096-o1-alias": (4096, 1, True, False, 0.95, 4),
+    "n16384-o2-alias-record": (16384, 2, True, True, 0.95, 4),
+    "n16384-o1": (16384, 1, False, False, 0.95, 4),
+    "n4096-mostly-retired": (4096, 2, False, True, 0.05, 4),
+    "n4096-resident-for-tens": (4096, 2, False, True, 0.95, 3),
+}
+
+
+@pytest.mark.parametrize("case", sorted(STAGED_CASES))
+def test_staged_advance_matches_single_stage_chunks(case):
+    n, order, alias, record, alive_share, nb = STAGED_CASES[case]
+    assert len(stage_widths(n)) == 3
+    bg, pair, v_iters = _pair_args(n_verts=1200, n_edges=9000, nb=nb, b0=0, b1=1,
+                                   weighted=alias)
+    walks = _batch(bg, n, blocks=(0, 1), seed=n + nb, alive_share=alive_share)
+    key = jax.random.PRNGKey(5)
+    sc = (jnp.int32(60), jnp.float32(1.0 if nb == 3 else 0.95),
+          jnp.float32(4.0), jnp.float32(0.25))
+    kw = dict(order=order, k_max=8 if order == 2 else 1, n_iters=16, v_iters=v_iters,
+              record=record, has_alias=alias, max_len=60)
+    out = advance_pair(*pair, *walks, key, *sc, **kw)
+    joined, steps, trace = _chunked(pair, walks, key, sc, kw)
+    for a, b in zip(out[:4], joined):
+        np.testing.assert_array_equal(np.asarray(a), b)
+    assert int(out[4]) == steps > 0
+    if record:
+        np.testing.assert_array_equal(np.asarray(out[5]), trace)
+    moved = np.asarray(out[2]) - np.asarray(walks[3])
+    if nb == 3:
+        assert moved.max() >= 10  # some walks stay resident for tens of hops
+    # the narrower stages ran: fewer lanes than the full width each iteration
+    assert int(out[6]) < n * (moved.max() + 1)
+
+
+def test_staged_advance_matches_the_fused_kernel():
+    bg, pair, v_iters = _pair_args(n_verts=1200, n_edges=9000, b0=0, b1=1)
+    walks = _batch(bg, 1024, blocks=(0, 1), seed=9)
+    assert stage_widths(1024) == (1024, 256)
+    key = jax.random.PRNGKey(13)
+    sc = (jnp.int32(20), jnp.float32(0.95), jnp.float32(4.0), jnp.float32(0.25))
+    kw = dict(order=2, k_max=8, n_iters=16, v_iters=v_iters,
+              record=True, has_alias=False, max_len=20)
+    ref = advance_pair(*pair, *walks, key, *sc, **kw)
+    fus = fused_advance_pair(*pair, *walks, key, *sc, **kw, interpret=True)
+    for a, b in zip(ref[:6], fus[:6]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("batch", ["all-leave-after-one-hop", "none-resident"])
+def test_lane_iters(batch):
+    bg, pair, v_iters = _pair_args(n_verts=1200, n_edges=9000, b0=0, b1=1)
+    n = 4096
+    wid, prev, cur, hop, alive = _batch(bg, n, blocks=(0, 1), seed=3)
+    deg = np.diff(bg.graph.indptr)
+    starts = np.asarray(cur)
+    # every walk starts at a vertex it can leave: one hop of length 1 ends it
+    cur = jnp.asarray(np.where(deg[starts] > 0, starts, prev))
+    assert (deg[np.asarray(cur)] > 0).all()
+    alive = jnp.full(n, batch == "all-leave-after-one-hop")
+    key = jax.random.PRNGKey(0)
+    sc = (jnp.int32(1), jnp.float32(1.0), jnp.float32(4.0), jnp.float32(0.25))
+    kw = dict(order=2, k_max=8, n_iters=16, v_iters=v_iters,
+              record=False, has_alias=False, max_len=1)
+    out = advance_pair(*pair, wid, prev, cur, jnp.zeros(n, jnp.int32), alive, key, *sc, **kw)
+    if batch == "none-resident":
+        assert int(out[4]) == int(out[6]) == 0
+    else:
+        assert int(out[4]) == n
+        assert int(out[6]) == n
 
 
 def test_ops_wrapper_pads_and_dispatches():

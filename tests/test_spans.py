@@ -230,6 +230,31 @@ def test_engine_spans(tmp_path, kron10, async_pipeline):
     assert c["prefetch_wait_time"] == stats.spans.seconds("blocks.prefetch_wait")
 
 
+def test_fetch_span_counts_the_advance_lane_iterations(tmp_path, kron10, monkeypatch):
+    """The ``n`` of each ``advance.fetch`` is the call's ``lane_iters``, and
+    ``IOStats.advance_lane_iters`` sums them."""
+    from repro.engines import base
+
+    counts = []
+    advance_pair = base.advance_pair
+
+    def counted(*args, **kw):
+        out = advance_pair(*args, **kw)
+        counts.append(int(out[6]))
+        return out
+
+    monkeypatch.setattr(base, "advance_pair", counted)
+    with write_and_open(kron10, str(tmp_path / "g")) as disk:
+        engine, res, calls = _run_counted(disk, async_pipeline=False)
+    stats = engine.stats
+    fetches = [s.n for s in stats.spans.records(-np.inf, np.inf) if s.name == "advance.fetch"]
+    assert len(counts) == len(calls) > 0
+    assert fetches == counts
+    assert stats.advance_lane_iters == sum(counts) == res.stats.as_dict()["advance_lane_iters"]
+    # every step took one lane of one iteration
+    assert stats.advance_lane_iters >= stats.steps_sampled > 0
+
+
 def test_blockstore_counters_are_span_totals(small_blocked):
     stats = IOStats()
     store = BlockStore(small_blocked, stats, capacity=2, enable_prefetch=True)
@@ -255,7 +280,7 @@ def test_blockstore_counters_are_span_totals(small_blocked):
 
 def test_advance_scopes_in_the_compiled_program():
     i32 = jnp.int32
-    V, E, N = 64, 256, 8
+    V, E, N = 64, 256, 512  # two stages: the compaction is in the program
 
     def s(shape, dtype=i32):
         return jax.ShapeDtypeStruct(shape, dtype)
@@ -270,5 +295,5 @@ def test_advance_scopes_in_the_compiled_program():
         *args, order=2, k_max=4, n_iters=9, v_iters=8, record=True, has_alias=False, max_len=10
     )
     op_names = set(re.findall(r'op_name="([^"]*)"', lowered.compile().as_text()))
-    for scope in ("advance.locate", "advance.propose", "advance.hop"):
+    for scope in ("advance.locate", "advance.propose", "advance.hop", "advance.compact"):
         assert any(scope in name for name in op_names), scope

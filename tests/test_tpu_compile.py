@@ -5,7 +5,8 @@ Nothing here runs on a chip: the TPU compiler compiles for a described
 cannot (Mosaic refusals, programs that do not fit device memory).  The
 shapes are the full-view caps of the Kronecker scale-20 graph in 8
 edge-balanced blocks (what ``chip_smoke.py`` runs), hard-coded rather than
-generated, with a 4,096-walk batch.
+generated, with batches of 4,096 and 16,384 walks (the advance runs in
+three stages of narrowing width at both).
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and test workers import
@@ -20,13 +21,14 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.engines.step import advance_pair, remap_search_iters
+from repro.engines.step import advance_pair, remap_search_iters, stage_widths
 from repro.kernels.pair_advance import fused_advance_pair
 
 #: largest block of the Kronecker scale-20 graph in 8 edge-balanced blocks
 BLOCK_VERTS = 450_491
 BLOCK_EDGES = 3_925_872
-WALKS = 4096
+#: batch widths: the benchmark's calls pad to 8,192, its set-up's to 16,384
+WALKS = (4096, 16384)
 LENGTH = 80
 #: device memory of one v5e chip
 V5E_HBM_BYTES = 16 * 10**9
@@ -64,7 +66,7 @@ def no_compile_cache():
         compilation_cache.reset_cache()
 
 
-def _pair_args(sharding, has_alias: bool):
+def _pair_args(sharding, has_alias: bool, walks: int = WALKS[0]):
     """Shapes of ``ResidentPair.device_args()`` with two full views."""
 
     def s(shape, dtype):
@@ -83,11 +85,11 @@ def _pair_args(sharding, has_alias: bool):
         s((alias_n,), jnp.float32),  # alias_q
     )
     walks = (
-        s((WALKS,), jnp.int32),  # wid
-        s((WALKS,), jnp.int32),  # prev
-        s((WALKS,), jnp.int32),  # cur
-        s((WALKS,), jnp.int32),  # hop
-        s((WALKS,), jnp.bool_),  # alive
+        s((walks,), jnp.int32),  # wid
+        s((walks,), jnp.int32),  # prev
+        s((walks,), jnp.int32),  # cur
+        s((walks,), jnp.int32),  # hop
+        s((walks,), jnp.bool_),  # alive
         s((2,), jnp.uint32),  # key
         s((), jnp.int32),  # length
         s((), jnp.float32),  # decay
@@ -112,8 +114,10 @@ def _statics(order: int, has_alias: bool, record: bool):
 @pytest.mark.parametrize("record", [False, True], ids=["norecord", "record"])
 @pytest.mark.parametrize("has_alias", [False, True], ids=["uniform", "alias"])
 @pytest.mark.parametrize("order", [1, 2])
-def test_advance_pair_compiles_for_v5e(one_chip, no_compile_cache, order, has_alias, record):
-    args = _pair_args(one_chip, has_alias)
+@pytest.mark.parametrize("walks", WALKS)
+def test_advance_pair_compiles_for_v5e(one_chip, no_compile_cache, walks, order, has_alias, record):
+    assert len(stage_widths(walks)) == 3
+    args = _pair_args(one_chip, has_alias, walks)
     compiled = advance_pair.lower(*args, **_statics(order, has_alias, record)).compile()
     mem = compiled.memory_analysis()
     total = mem.argument_size_in_bytes + mem.output_size_in_bytes + mem.temp_size_in_bytes
