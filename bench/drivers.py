@@ -1,14 +1,13 @@
-"""Drive the system under test through one window of a batch job.
+"""The window machinery that every mode shares.
 
-The driver takes the program only through its public entry point
-(``BiBlockEngine``) and the engine's own advance seam, and records around it
-with the benchmark's clock: the window's two ends, the program's ``IOStats``
-counters at both, the compiles inside, the device trace (``--trace 1``) and,
-for the reference, the walks the window produced.
-
-The window opens at the first advance-call completion after the
-initialization stage and ``warmup_supersteps`` supersteps, and closes at the
-first completion ``seconds`` later; the run stops there.
+A mode is a file ``modes/<mode>.py``, named by a traffic mix's ``mode``; its
+``run(graph, config, traffic, seeds, seconds, trace_dir, compiles, log,
+devices)`` drives the program through one window and returns the result the
+harness reads: ``kind`` (a file ``kinds/<kind>.py`` that judges it),
+``window`` (a :class:`Window`), ``attempted``, ``failed``, and what its kind
+reads.  Here: the program's ``IOStats`` counters read at both ends of the
+window, the compiles inside it, the device trace (``--trace 1``), and host
+spans in that trace.  A new mode imports these and changes none of them.
 """
 
 from __future__ import annotations
@@ -20,7 +19,6 @@ import numpy as np
 import jax
 
 import tracing
-from generator import job_sources
 
 clock = time.perf_counter
 
@@ -139,81 +137,3 @@ class WindowClosed(Exception):
 
 def filled(corpus: np.ndarray) -> np.ndarray:
     return (corpus >= 0).sum(1)
-
-
-def run_job(sut, config: dict, traffic: dict, seeds, seconds: float, trace_dir, compiles, log):
-    from repro.core.transition import rwnv_task
-    from repro.engines.biblock import BiBlockEngine
-
-    walk, engine_cfg, storage = config["walk"], config["engine"], config["storage"]
-    t0 = clock()
-    sources = job_sources(sut.num_vertices, traffic, walk["walks_per_vertex"])
-    task = rwnv_task(
-        p=walk["p"],
-        q=walk["q"],
-        walks_per_vertex=walk["walks_per_vertex"],
-        length=walk["length"],
-        seed=seeds.walk,
-    )
-    engine = BiBlockEngine(
-        sut.disk,
-        task,
-        pool=storage["walk_pool"],
-        block_cache_blocks=storage["block_cache_blocks"],
-        loading=engine_cfg["loading"],
-        async_pipeline=engine_cfg["async_pipeline"],
-        k_max=engine_cfg["k_max"],
-        record_walks=engine_cfg["record_walks"],
-        initial_walks=sources,
-    )
-    log("engine_s", clock() - t0)
-    t_warm = clock()
-    warmup = int(traffic.get("warmup_supersteps", 1))
-    win = Window(engine.stats, compiles, trace_dir)
-    state = {"calls": 0}
-    ended = []
-    advance = engine._advance
-
-    def observed(batch, wid, alive=None):
-        with annotate("advance"):
-            out = advance(batch, wid, alive)
-        if win.t_open is None:
-            if engine.stats.supersteps > warmup:
-                log("warmup_s", clock() - t_warm)
-                state["filled_open"] = filled(engine.corpus)
-                win.open()
-        else:
-            state["calls"] += 1
-            # walks that this call retired, for the reference to judge
-            was = np.ones(len(wid), bool) if alive is None else np.asarray(alive, bool)
-            ended.append(np.asarray(wid)[was & ~np.asarray(out[1], bool)])
-            win.boundary()
-            if clock() - win.t_open >= seconds:
-                win.close()
-                state["filled_close"] = filled(engine.corpus)
-                raise WindowClosed
-        return out
-
-    engine._advance = observed
-    try:
-        engine.run()
-    except WindowClosed:
-        pass
-    else:
-        raise RuntimeError("the job ended before its window closed; give it more walks")
-    return {
-        "kind": "batch",
-        "window": win,
-        "attempted": state["calls"],
-        "failed": 0,
-        "corpus": engine.corpus,
-        "sources": sources,
-        "filled_open": state["filled_open"],
-        "filled_close": state["filled_close"],
-        "ended": np.concatenate(ended) if ended else np.zeros(0, np.int64),
-        "walk": walk,
-        "k_max": engine_cfg["k_max"],
-    }
-
-
-DRIVERS = {"job": run_job}

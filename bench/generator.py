@@ -1,6 +1,8 @@
 """The one traffic generator: every mix is a data file under ``traffic/``.
 
-One mode so far, named by the file's ``mode``:
+A mix's ``mode`` names the file ``modes/<mode>.py`` that drives the program
+with what this generator makes from the mix's parameters; a new mode adds
+its file there, and here only a generator of inputs that no mode makes yet.
 
 ``job``  a batch job: walks start from every ``source_stride``-th vertex,
          ``walks_per_vertex`` (the configuration's) walks each.

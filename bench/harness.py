@@ -1,10 +1,24 @@
 """Run one cell of ``BENCHMARK.json`` once and assemble its result line.
 
-Everything that belongs to one configuration, traffic mix or metric is a file
-found by its name: ``configs/<config>.json``, ``traffic/<traffic>.json`` and
-``metrics/<metric>.py`` (a ``read(r)`` that returns a number, or ``None``
-when the run has nothing for it to read).  Adding a cell, a mix or a metric
-adds files and entries; nothing here changes.
+Everything that belongs to one configuration, traffic mix, mode, result kind
+or metric is a file found by its name:
+
+* ``configs/<config>.json``: the deployment's sizes;
+* ``traffic/<traffic>.json``: the mix's parameters, ``mode`` among them;
+* ``modes/<mode>.py``: a ``run(graph, config, traffic, seeds, seconds,
+  trace_dir, compiles, log, devices)`` that drives the program through one
+  window (``drivers.py`` has the machinery every mode shares) and returns
+  the result, whose ``kind`` names the check;
+* ``kinds/<kind>.py``: a ``check(out, graph, audit, rng, *, control=False)``
+  that judges that result against the plain reference, each number beside
+  its limit;
+* ``metrics/<metric>.py``: a ``read(r)`` that returns a number, or ``None``
+  when the run has nothing for it to read.
+
+The harness makes the cell's graph (the benchmark's CSR, which the reference
+keeps) and hands it to the mode with the cell's devices; the mode gives it
+to the program in the form that entry point reads.  Adding a cell, a mix, a
+mode, a kind or a metric adds files and entries; nothing here changes.
 """
 
 from __future__ import annotations
@@ -55,13 +69,29 @@ def load_traffic(name: str, bench_dir: Path = BENCH_DIR) -> dict:
     return json.loads((bench_dir / "traffic" / f"{name}.json").read_text())
 
 
-def load_metric(name: str, bench_dir: Path = BENCH_DIR):
-    """The ``read`` function of ``metrics/<name>.py``."""
-    path = bench_dir / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}", path)
+def _load(folder: str, name: str, function: str, bench_dir: Path):
+    path = bench_dir / folder / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no {folder[:-1]} {name!r}: {path} is missing")
+    spec = importlib.util.spec_from_file_location(f"bench_{folder}_{name}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return getattr(module, function)
+
+
+def load_metric(name: str, bench_dir: Path = BENCH_DIR):
+    """The ``read`` function of ``metrics/<name>.py``."""
+    return _load("metrics", name, "read", bench_dir)
+
+
+def load_mode(name: str, bench_dir: Path = BENCH_DIR):
+    """The ``run`` function of ``modes/<name>.py``."""
+    return _load("modes", name, "run", bench_dir)
+
+
+def load_kind(name: str, bench_dir: Path = BENCH_DIR):
+    """The ``check`` function of ``kinds/<name>.py``."""
+    return _load("kinds", name, "check", bench_dir)
 
 
 def _applies(metric: dict, cell: dict, reported: set) -> bool:
@@ -141,16 +171,17 @@ def make_graph(config: dict, seeds: Seeds, log, *, chips: int | None = None):
 
 
 class SystemUnderTest:
-    """The cell's graph written to a block file and opened through the program."""
+    """The cell's graph written to a block file and opened through the
+    program, for a mode whose entry point reads the graph from disk."""
 
     def __init__(self, config: dict, graph, log):
         from repro.core.graph import BlockedGraph, CSRGraph
         from repro.io import write_and_open
 
         t = clock()
-        self.indptr, self.indices, starts = graph
+        indptr, indices, starts = graph
         # the program gets its own copy; the reference keeps the benchmark's
-        bg = BlockedGraph(CSRGraph(self.indptr.copy(), self.indices.copy()), starts)
+        bg = BlockedGraph(CSRGraph(indptr.copy(), indices.copy()), starts)
         self._dir = tempfile.TemporaryDirectory(prefix="bench_blocks_")
         self.disk = write_and_open(
             bg, self._dir.name, io_coalesce_gap=config["engine"]["io_coalesce_gap"]
@@ -213,18 +244,16 @@ def run_cell(
     cell's graph when the caller made it (:func:`make_graph`); otherwise it is
     made here.  With ``control`` the same window is also judged with the
     control in the program's place (``control_checks``); the benchmark's own
-    runs never ask for it."""
+    runs never ask for it.  ``devices`` are the cell's chips, which the mode
+    is given."""
     import drivers
     import reference
     import tracing
-    from checks import CHECKS
 
     bench_dir = root / "bench"
     config = load_config(bench, cell, root) if config is None else config
     traffic = load_traffic(cell["traffic"], bench_dir) if traffic is None else traffic
-    kind = drivers.DRIVERS[traffic["mode"]]
-    if not config["engine"]["record_walks"]:
-        raise ValueError("the reference audits the recorded walks: record_walks must be on")
+    mode = load_mode(traffic["mode"], bench_dir)
 
     def log(name, value):
         emit(f"[setup] {name}={value}")
@@ -233,26 +262,24 @@ def run_cell(
     compiles = drivers.CompileCounter()
     if graph is None:
         graph = make_graph(config, seeds, log)
-    sut = SystemUnderTest(config, graph, log)
-    del graph
+    indptr, indices, _ = graph
     trace_dir = tempfile.mkdtemp(prefix="bench_trace_") if trace else None
-    try:
-        out = kind(sut, config, traffic, seeds, seconds, trace_dir, compiles, log)
-        setup_s = out["window"].t_open - t_start
-        emit(f"[setup] setup_s={setup_s} compiles={compiles.total - compiles.in_window}")
-        peak = memory_peak(devices)
-        win = out["window"]
-        emit(f"[window] seconds={win.seconds} calls={out['attempted']} compiles={compiles.in_window} "
-             + " ".join(f"{k}={v}" for k, v in win.counters.items()))
-    finally:
-        sut.close()
+    out = mode(graph, config, traffic, seeds, seconds, trace_dir, compiles, log, devices)
+    del graph
+    setup_s = out["window"].t_open - t_start
+    emit(f"[setup] setup_s={setup_s} compiles={compiles.total - compiles.in_window}")
+    peak = memory_peak(devices)
+    win = out["window"]
+    emit(f"[window] seconds={win.seconds} calls={out['attempted']} compiles={compiles.in_window} "
+         + " ".join(f"{k}={v}" for k, v in win.counters.items()))
     reduced = None
     if trace:
         reduced = tracing.reduce_events(tracing.load_events(trace_dir))
         _rmtree(trace_dir)
+    check = load_kind(out["kind"], bench_dir)
     t = clock()
-    graph = reference.ReferenceGraph(sut.indptr, sut.indices)
-    checks = CHECKS[out["kind"]](out, graph, config["audit"], seeds.audit_rng())
+    graph = reference.ReferenceGraph(indptr, indices)
+    checks = check(out, graph, config["audit"], seeds.audit_rng())
     emit(f"[check] reference_s={clock() - t}")
     correct = all(c["value"] <= c["limit"] for c in checks)
 
@@ -284,7 +311,7 @@ def run_cell(
             "idle_gaps": reduced["idle_gaps"],
         }
     if control:
-        ctrl = CHECKS[out["kind"]](out, graph, config["audit"], seeds.audit_rng(), control=True)
+        ctrl = check(out, graph, config["audit"], seeds.audit_rng(), control=True)
         result["control_checks"] = _named(ctrl)
     result["checks"] = _named(checks)
     return result

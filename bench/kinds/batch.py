@@ -1,7 +1,5 @@
-"""The comparison that decides ``correct``: the window's output against the
-plain reference (:mod:`reference`), each number beside its limit.
-
-Batch (the corpus the window recorded):
+"""``batch``: the corpus a window recorded, against the plain reference
+(:mod:`reference`), each number beside its limit.
 
 * ``source_mismatch``  rows whose first vertex is not the walk's source;
 * ``unrecorded_steps`` |steps the program counted in the window - hops
@@ -13,7 +11,9 @@ Batch (the corpus the window recorded):
 
 Every limit comes from the configuration's ``audit.limits``, and the
 sample size of ``bias_z`` from ``audit.bias_sample``; a number passes when
-it is at most its limit.
+it is at most its limit.  A mode whose result has this kind returns the
+keys read below: ``corpus``, ``sources``, ``filled_open``, ``filled_close``,
+``ended``, ``walk``, ``k_max`` and the ``window``.
 """
 
 from __future__ import annotations
@@ -27,7 +27,9 @@ def _check(name: str, value, limits: dict) -> dict:
     return {"name": name, "value": value, "limit": limits[name]}
 
 
-def check_batch(out: dict, graph: reference.ReferenceGraph, audit: dict, rng, *, control=False):
+def check(out: dict, graph: reference.ReferenceGraph, audit: dict, rng, *, control=False):
+    if out.get("corpus") is None:
+        raise ValueError("the reference audits the recorded walks: record_walks must be on")
     limits = audit["limits"]
     corpus = out["corpus"]
     prev, cur, nxt, _ = reference.walk_hops(corpus, out["filled_open"], out["filled_close"])
@@ -55,6 +57,3 @@ def check_batch(out: dict, graph: reference.ReferenceGraph, audit: dict, rng, *,
         _check("bias_z", hops["bias_z"], limits),
         _check("short_walks", int(short.sum()), limits),
     ]
-
-
-CHECKS = {"batch": check_batch}

@@ -3,10 +3,12 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``workloads`` in ``BENCHMARK.json``) names a configuration
-(``bench/configs/``) and a traffic mix (``bench/traffic/``).  The run makes
-the graph from ``--seed``, writes it to a block file, warms up, measures for
-``--seconds``, and checks what the window produced against the plain
-reference (``bench/reference.py``).  Earlier lines itemise set-up; the last
+(``bench/configs/``) and a traffic mix (``bench/traffic/``), whose ``mode``
+names the file ``bench/modes/<mode>.py`` that drives the program.  The run
+makes the graph from ``--seed``, hands it to the mode (the corpus job
+writes it to a block file), warms up, measures for ``--seconds``, and
+checks what the window produced against the plain reference, by the
+result's kind (``bench/kinds/``).  Earlier lines itemise set-up; the last
 line on stdout is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the end-to-end metrics, or with ``--trace 1`` the per-layer
 ones), ``device``, with ``--trace 1`` ``breakdown``, and last ``checks``

@@ -3,6 +3,7 @@ cell driven end to end on the CPU at a tiny scale, the chip check skipped."""
 
 from __future__ import annotations
 
+import shutil
 import sys
 import time
 from pathlib import Path
@@ -17,6 +18,15 @@ import harness  # noqa: E402
 
 #: Kronecker scale of the CPU runs: 1,024 vertices, about 20,000 edges
 TINY_SCALE = 10
+
+
+def copy_checkout(tmp_path: Path) -> Path:
+    """A checkout holding a copy of the benchmark alone (``bench/`` and
+    ``BENCHMARK.json``), for a test that adds files and entries to it."""
+    root = tmp_path / "checkout"
+    shutil.copytree(BENCH, root / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(ROOT / "BENCHMARK.json", root / "BENCHMARK.json")
+    return root
 
 
 def tiny_run(workload: str, *, seed: int = 7, seconds: float = 0.5, trace: bool = False,
@@ -40,7 +50,7 @@ def tiny_run(workload: str, *, seed: int = 7, seconds: float = 0.5, trace: bool 
         seed=seed,
         seconds=seconds,
         trace=trace,
-        devices=jax.devices()[:1],
+        devices=jax.devices()[: cell["chips"]],
         t_start=time.perf_counter(),
         config=config,
         traffic=traffic,
