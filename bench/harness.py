@@ -13,7 +13,14 @@ or metric is a file found by its name:
   that judges that result against the plain reference, each number beside
   its limit;
 * ``metrics/<metric>.py``: a ``read(r)`` that returns a number, or ``None``
-  when the run has nothing for it to read.
+  when the run has nothing for it to read.  With ``--trace 1``, ``r.trace``
+  holds the device trace's reduction (``tracing.reduce_events``), among it
+  ``module_s``: device seconds per jit module, so a reader finds its own
+  program's time by the module's name.
+
+A cell runs on ``cell["chips"]`` devices, and so it does in the CPU tests:
+``tests/bench/benchtest_util.py`` runs a cell that asks for more devices
+than the test process has in a child process with that many host devices.
 
 The harness makes the cell's graph (the benchmark's CSR, which the reference
 keeps) and hands it to the mode with the cell's devices; the mode gives it

@@ -37,8 +37,14 @@ def peaks_for(device_kind: str) -> dict:
     return table[device_kind]
 
 
-def advance_roofline_pct(steps: int, advance_s: float, *, record: bool, hbm_bytes_per_s: float):
-    """Share (%) of the bytes-bound roofline; ``None`` without device time."""
+def advance_roofline_pct(
+    steps: int, advance_s: float, *, record: bool, hbm_bytes_per_s: float, chips: int = 1
+):
+    """Share (%) of the bytes-bound roofline of ``chips`` chips, each with
+    ``hbm_bytes_per_s``: the walk's bytes over the bandwidth of every chip the
+    program ran on, against its device time (``advance_s``, per chip, as the
+    trace reduction averages it); ``None`` without device time."""
     if advance_s <= 0 or steps <= 0:
         return None
-    return 100.0 * steps * advance_bytes_per_step(record=record) / hbm_bytes_per_s / advance_s
+    bytes_per_s = chips * hbm_bytes_per_s
+    return 100.0 * steps * advance_bytes_per_step(record=record) / bytes_per_s / advance_s

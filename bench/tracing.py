@@ -9,6 +9,10 @@ the tuples alone, so a small recorded trace checks it without a chip.
   averaged over the device planes.
 * ``advance_s``: summed duration of the ``XLA Modules`` events whose name
   holds ``pair_advance`` (the jit module of ``pair_advance_impl``).
+* ``module_s``: device seconds of each ``XLA Modules`` name, its
+  ``(<hash>)`` suffix stripped (``jit_pair_advance_impl``), averaged over the
+  device planes as ``advance_s`` is: a reader finds its own program's
+  device time by the module's name.
 * ``device_ops``: the ten op names with the most device time.
 * ``idle_gaps``: the ten longest gaps between busy intervals, each named by
   the benchmark's own host span that covers its middle
@@ -21,6 +25,7 @@ from __future__ import annotations
 
 import glob
 import os
+import re
 from typing import Iterable, List, Optional, Tuple
 
 import numpy as np
@@ -30,6 +35,9 @@ Event = Tuple[str, str, str, float, float]
 
 ADVANCE_MODULE = "pair_advance"
 SPAN_PREFIX = "bench:"
+
+#: the program hash XLA appends to a module's name: ``jit_f(1234)``
+_MODULE_HASH = re.compile(r"\([^()]*\)$")
 
 
 def start(directory: str) -> None:
@@ -115,12 +123,18 @@ def reduce_events(events: Iterable[Event]) -> Optional[dict]:
     for e in ops:
         totals[e[2]] = totals.get(e[2], 0.0) + e[4]
     device_ops = sorted(totals.items(), key=lambda kv: -kv[1])[:10]
-    advance_ns = sum(e[4] for e in events if e[1] == "XLA Modules" and ADVANCE_MODULE in e[2])
+    modules = [e for e in events if e[1] == "XLA Modules"]
+    advance_ns = sum(e[4] for e in modules if ADVANCE_MODULE in e[2])
+    module_ns: dict = {}
+    for e in modules:
+        name = _MODULE_HASH.sub("", e[2])
+        module_ns[name] = module_ns.get(name, 0.0) + e[4]
     gaps.sort(key=lambda g: -g[0])
     idle = [[_span_at(spans, (a + b) / 2), g / 1e9] for g, a, b in gaps[:10]]
     return {
         "busy_s": busy_s,
         "advance_s": advance_ns / len(planes) / 1e9,
+        "module_s": {name: ns / len(planes) / 1e9 for name, ns in module_ns.items()},
         "device_ops": [[name, ns / 1e9] for name, ns in device_ops],
         "idle_gaps": idle,
         "devices": len(planes),

@@ -10,7 +10,7 @@ from pathlib import Path
 
 import pytest
 
-from benchtest_util import BENCH, ROOT, copy_checkout, tiny_run
+from benchtest_util import BENCH, FOUR_CHIP_CELL, ROOT, add_four_chip_cell, copy_checkout, tiny_run
 
 import harness
 
@@ -18,28 +18,29 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 
 
-def test_every_entry_resolves_to_its_files():
-    bench = harness.load_benchmark()
+def _entries_resolve(root: Path) -> None:
+    bench = harness.load_benchmark(root)
+    bench_dir = root / "bench"
     assert set(bench) == {"command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer"}
     for cfg in bench["configs"]:
-        assert NAME.match(cfg["name"]) and (ROOT / cfg["file"]).is_file()
+        assert NAME.match(cfg["name"]) and (root / cfg["file"]).is_file()
         assert all(NAME.match(k) for k in cfg["reduced"])
     for cell in bench["workloads"]:
         assert NAME.match(cell["name"]) and cell["chips"] in (1, 4)
-        harness.load_config(bench, cell)
-        mode = harness.load_traffic(cell["traffic"])["mode"]
-        assert NAME.match(mode) and (BENCH / "modes" / f"{mode}.py").is_file()
-        assert callable(harness.load_mode(mode))
+        harness.load_config(bench, cell, root)
+        mode = harness.load_traffic(cell["traffic"], bench_dir)["mode"]
+        assert NAME.match(mode) and (bench_dir / "modes" / f"{mode}.py").is_file()
+        assert callable(harness.load_mode(mode, bench_dir))
         for trace in (False, True):
             for m in harness.cell_metrics(bench, cell, trace):
-                assert callable(harness.load_metric(m["name"]))
+                assert callable(harness.load_metric(m["name"], bench_dir))
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert NAME.match(m["name"]) and UNIT.match(m["unit"]) and m["better"] in ("lower", "higher")
     assert any(m["name"] == "setup_s" for m in bench["end_to_end"])
 
 
-def test_each_cell_reports_setup_another_end_to_end_and_a_per_layer_metric():
-    bench = harness.load_benchmark()
+def _cells_report(root: Path) -> None:
+    bench = harness.load_benchmark(root)
     for cell in bench["workloads"]:
         e2e = {m["name"] for m in harness.cell_metrics(bench, cell, False)}
         assert "setup_s" in e2e and len(e2e) >= 2
@@ -47,8 +48,7 @@ def test_each_cell_reports_setup_another_end_to_end_and_a_per_layer_metric():
         assert per_layer and all(m["moves"] in e2e for m in per_layer)
 
 
-@pytest.mark.parametrize("workload", [c["name"] for c in harness.load_benchmark()["workloads"]])
-def test_the_kind_a_cell_returns_is_a_file(monkeypatch, workload):
+def _kind_is_a_file(monkeypatch, root: Path, workload: str) -> None:
     kinds = []
     load_kind = harness.load_kind
 
@@ -57,9 +57,45 @@ def test_the_kind_a_cell_returns_is_a_file(monkeypatch, workload):
         return load_kind(name, bench_dir)
 
     monkeypatch.setattr(harness, "load_kind", seen)
-    result, _ = tiny_run(workload)
-    assert result["correct"] is True
-    assert kinds and all(NAME.match(k) and (BENCH / "kinds" / f"{k}.py").is_file() for k in kinds)
+    result, _ = tiny_run(workload, root=root)
+    assert result["correct"] is True, result["checks"]
+    assert kinds and all(NAME.match(k) and (root / "bench" / "kinds" / f"{k}.py").is_file() for k in kinds)
+
+
+def test_every_entry_resolves_to_its_files():
+    _entries_resolve(ROOT)
+
+
+def test_each_cell_reports_setup_another_end_to_end_and_a_per_layer_metric():
+    _cells_report(ROOT)
+
+
+@pytest.mark.parametrize("workload", [c["name"] for c in harness.load_benchmark()["workloads"]])
+def test_the_kind_a_cell_returns_is_a_file(monkeypatch, workload):
+    _kind_is_a_file(monkeypatch, ROOT, workload)
+
+
+@pytest.fixture(scope="module")
+def four_chip_checkout(tmp_path_factory):
+    """A copied checkout that gains a cell with ``chips`` 4 as new files and
+    entries alone (``benchtest_util.add_four_chip_cell``)."""
+    root = copy_checkout(tmp_path_factory.mktemp("four_chips"))
+    before = _files(root)
+    add_four_chip_cell(root)
+    assert _changed(root, before) == {Path("BENCHMARK.json")}
+    return root
+
+
+@pytest.mark.parametrize("check", ["entries_resolve", "cells_report", "kind_is_a_file"])
+def test_a_four_chip_cell_added_as_files_passes_each_per_cell_check(monkeypatch, four_chip_checkout, check):
+    """The checks above, on a checkout with a four-chip cell beside
+    ``rwnv.kron20``; this process has one device, the cell's run four."""
+    if check == "entries_resolve":
+        _entries_resolve(four_chip_checkout)
+    elif check == "cells_report":
+        _cells_report(four_chip_checkout)
+    else:
+        _kind_is_a_file(monkeypatch, four_chip_checkout, FOUR_CHIP_CELL)
 
 
 @pytest.mark.parametrize("folder", ["modes", "kinds", "metrics"])

@@ -29,3 +29,12 @@ def test_roofline_share():
     # 819e9 B/s moves 40 B x 20.475e9 steps in one second
     assert roofline.advance_roofline_pct(20_475_000_000, 1.0, record=True, hbm_bytes_per_s=819e9) == pytest.approx(100.0)
     assert roofline.advance_roofline_pct(1000, 0.0, record=True, hbm_bytes_per_s=819e9) is None
+
+
+def test_roofline_share_over_four_chips():
+    """The same work on four chips has four times the bandwidth to meet it."""
+    args = (1_000_000_000, 0.5)
+    one = roofline.advance_roofline_pct(*args, record=True, hbm_bytes_per_s=819e9)
+    assert roofline.advance_roofline_pct(*args, record=True, hbm_bytes_per_s=819e9, chips=1) == one
+    four = roofline.advance_roofline_pct(*args, record=True, hbm_bytes_per_s=819e9, chips=4)
+    assert four == pytest.approx(one / 4)
