@@ -155,6 +155,13 @@ def activated_bytes(degrees: np.ndarray, vertices: np.ndarray) -> int:
     return int(8 * vertices.size + 4 * deg.sum())
 
 
+def compact_indptr(degrees: np.ndarray) -> np.ndarray:
+    """``[K+1]`` int32 offsets of a compact CSR whose rows have ``degrees``."""
+    indptr = np.zeros(len(degrees) + 1, dtype=np.int32)
+    np.cumsum(degrees, out=indptr[1:])
+    return indptr
+
+
 @dataclasses.dataclass
 class ResidentBlock:
     """One block resident in "memory" (device arrays, statically padded).
@@ -249,68 +256,60 @@ class BlockView:
         )
 
     @classmethod
-    def from_rows(
+    def from_flat(
         cls,
         block_id: int,
         vids: np.ndarray,
-        segs: Sequence[np.ndarray],
-        alias_segs: Optional[Sequence] = None,
-        *,
-        kind: str = "activated",
+        indptr: np.ndarray,
+        indices: np.ndarray,
+        alias_j: Optional[np.ndarray] = None,
+        alias_q: Optional[np.ndarray] = None,
     ) -> "BlockView":
-        """Assemble a view from per-vertex row segments (``vids`` sorted,
-        ``segs[k]`` the neighbor list of ``vids[k]``)."""
-        k = len(segs)
-        indptr = np.zeros(k + 1, dtype=np.int32)
-        if k:
-            sizes = np.array([s.size for s in segs], dtype=np.int64)
-            indptr[1:] = np.cumsum(sizes).astype(np.int32)
-        indices = np.concatenate(segs).astype(np.int32) if k else np.zeros(0, np.int32)
-        alias_j = alias_q = None
-        if alias_segs is not None:
-            alias_j = (
-                np.concatenate([a for a, _ in alias_segs]).astype(np.int32)
-                if k
-                else np.zeros(0, np.int32)
-            )
-            alias_q = (
-                np.concatenate([q for _, q in alias_segs]).astype(np.float32)
-                if k
-                else np.zeros(0, np.float32)
-            )
+        """An activated view over a compact CSR laid out flat (``vids``
+        sorted, the row of ``vids[k]`` at ``indices[indptr[k]:indptr[k+1]]``),
+        in the view's dtypes."""
         return cls(
             block_id=block_id,
-            kind=kind,
+            kind="activated",
             vids=np.asarray(vids, dtype=np.int32),
-            indptr=indptr,
-            indices=indices,
-            alias_j=alias_j,
-            alias_q=alias_q,
+            indptr=np.asarray(indptr, dtype=np.int32),
+            indices=np.asarray(indices, dtype=np.int32),
+            alias_j=None if alias_j is None else np.asarray(alias_j, dtype=np.int32),
+            alias_q=None if alias_q is None else np.asarray(alias_q, dtype=np.float32),
         )
 
     def row(self, k: int) -> np.ndarray:
         return self.indices[self.indptr[k] : self.indptr[k + 1]]
 
-    def _alias_row(self, k: int):
-        s, e = self.indptr[k], self.indptr[k + 1]
-        return (self.alias_j[s:e], self.alias_q[s:e])
-
     def extended(self, other: "BlockView") -> "BlockView":
         """A new activated view holding this view's rows plus ``other``'s
         (the mid-advance *extension gather*: ``other`` carries the rows of
         vertices reached during execution that were not pre-activated).
-        Vertex sets must be disjoint."""
+        Vertex sets must be disjoint.  Rows are merged in vid order by
+        array operations: each view's cells keep their order, so a per-cell
+        mask of the cells that come from ``other`` places both at once."""
         if other.block_id != self.block_id:
             raise ValueError("cannot extend a view with rows of another block")
-        merged = np.concatenate([self.vids, other.vids])
-        order = np.argsort(merged, kind="stable")
-        views = [self] * self.nverts + [other] * other.nverts
-        local = list(range(self.nverts)) + list(range(other.nverts))
-        segs = [views[i].row(local[i]) for i in order]
-        alias_segs = None
+        vids = np.concatenate([self.vids, other.vids])
+        order = np.argsort(vids, kind="stable")
+        deg = np.concatenate([np.diff(self.indptr), np.diff(other.indptr)])[order]
+        indptr = compact_indptr(deg)
+        from_other = np.repeat(order >= self.nverts, deg)
+        from_self = ~from_other
+
+        def merge(mine: np.ndarray, theirs: np.ndarray) -> np.ndarray:
+            out = np.empty(from_other.size, dtype=mine.dtype)
+            out[from_self] = mine[: self.indptr[-1]]
+            out[from_other] = theirs[: other.indptr[-1]]
+            return out
+
+        alias_j = alias_q = None
         if self.alias_j is not None:
-            alias_segs = [views[i]._alias_row(local[i]) for i in order]
-        return BlockView.from_rows(self.block_id, merged[order], segs, alias_segs, kind="activated")
+            alias_j = merge(self.alias_j, other.alias_j)
+            alias_q = merge(self.alias_q, other.alias_q)
+        return BlockView.from_flat(
+            self.block_id, vids[order], indptr, merge(self.indices, other.indices), alias_j, alias_q
+        )
 
 
 class BlockedGraph:
@@ -459,23 +458,22 @@ class BlockedGraph:
 
     def _rows_view(self, block_id: int, vids: np.ndarray) -> BlockView:
         g = self.graph
-        segs = [g.indices[g.indptr[v] : g.indptr[v + 1]] for v in vids]
-        alias_segs = None
+        rs, re = g.indptr[vids], g.indptr[vids + 1]
+        indptr = compact_indptr(re - rs)
+        # every cell's position in the host CSR: one gather copies all rows
+        shift = rs - indptr[:-1]
+        src = np.repeat(shift, re - rs) + np.arange(indptr[-1])
+        alias_j = alias_q = None
         if self._build_alias:
             from .sampling import build_alias  # local import: avoid cycle
 
-            alias_segs = []
-            for k, v in enumerate(vids):
-                w = (
-                    g.weights[g.indptr[v] : g.indptr[v + 1]]
-                    if g.weights is not None
-                    else np.ones(segs[k].size)
-                )
-                if segs[k].size:
-                    alias_segs.append(build_alias(w))
-                else:
-                    alias_segs.append((np.zeros(0, np.int32), np.zeros(0, np.float32)))
-        return BlockView.from_rows(block_id, vids, segs, alias_segs)
+            alias_j = np.zeros(src.size, dtype=np.int32)
+            alias_q = np.zeros(src.size, dtype=np.float32)
+            for k in np.flatnonzero(re > rs):
+                w = g.weights[rs[k] : re[k]] if g.weights is not None else np.ones(re[k] - rs[k])
+                lo, hi = indptr[k], indptr[k + 1]
+                alias_j[lo:hi], alias_q[lo:hi] = build_alias(w)
+        return BlockView.from_flat(block_id, vids, indptr, g.indices[src], alias_j, alias_q)
 
     def describe(self) -> dict:
         return {

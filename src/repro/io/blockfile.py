@@ -48,7 +48,7 @@ from __future__ import annotations
 import os
 import struct
 import tempfile
-from typing import Dict, Iterable, Optional
+from typing import Dict, Optional
 
 import numpy as np
 
@@ -59,6 +59,7 @@ from repro.core.graph import (
     ResidentBlock,
     activated_bytes,
     block_of,
+    compact_indptr,
 )
 from repro.io.ioplan import execute_plan, plan_reads
 
@@ -74,6 +75,7 @@ MAGIC = b"GRSWBLK1"
 VERSION = 1
 FLAG_WEIGHTED = 1 << 0
 _HEADER = struct.Struct("<8sII6Q")  # magic, version, flags, NB, V, E, maxv, maxe, rsvd
+_PAIR = struct.Struct("<ii")  # one vertex's index entry pair: its row's [start, end)
 #: conventional file name inside a ``--graph-dir`` directory
 BLOCK_FILE_NAME = "graph.grb"
 
@@ -414,78 +416,98 @@ class DiskBlockedGraph:
         return blk
 
     # -- on-demand path --------------------------------------------------------
-    def _read_rows_ext(self, b: int, vertices: Iterable[int]):
+    def _read_rows_ext(self, b: int, vertices: np.ndarray):
         """Partial reads of block ``b``'s requested rows — the access
-        pattern of the paper's Fig. 5(b).
+        pattern of the paper's Fig. 5(b) — into one flat compact CSR.
 
-        With ``io_coalesce_gap == 0`` (reference): for each unique vertex,
-        one ``pread`` of its 8-byte index-entry pair then one of its
-        neighbor segment.  With the planner on: the index pairs are fetched
-        by a few gap-split ranged reads over ``[min_v, max_v]`` of the index
-        region, the resulting row extents merge into gap-aware coalesced
-        ranges, and segments are sliced out in memory — same bytes charged,
-        far fewer syscalls.  Returns ``(vs, rows, extents)`` with ``vs``
-        sorted, ``rows[k]`` the global neighbor ids of ``vs[k]`` and
-        ``extents[k] = (rs, re)`` its within-block edge range (reused by the
-        alias reader so the index pair is never fetched twice)."""
+        The output is sized from the resident degrees before any read, and
+        every index pair read must agree with them.  With
+        ``io_coalesce_gap == 0`` (reference): for each unique vertex, one
+        ``pread`` of its 8-byte index-entry pair then one of its neighbor
+        segment, straight into the segment's slice of the output.  With the
+        planner on: the index pairs are fetched by a few gap-split ranged
+        reads over ``[min_v, max_v]`` of the index region, the row extents
+        merge into gap-aware coalesced ranges, and segments are sliced out
+        in memory — same bytes charged, far fewer syscalls.  Returns
+        ``(vs, indptr, indices, rs)``: ``vs`` sorted, the row of ``vs[k]``
+        at ``indices[indptr[k]:indptr[k+1]]``, and ``rs[k]`` its
+        within-block edge start (reused by the alias reader so the index
+        pair is never fetched twice)."""
         s, e = int(self.block_starts[b]), int(self.block_starts[b + 1])
-        vs = np.unique(np.asarray(list(vertices), dtype=np.int64))
+        vs = np.unique(np.asarray(vertices, dtype=np.int64))
+        if vs.size and (vs[0] < s or vs[-1] >= e):
+            raise IndexError(f"vertices outside block {b} range [{s}, {e})")
+        estart = int(self._indptr[s])
+        rs = self._indptr[vs] - estart
+        re = self._indptr[vs + 1] - estart
+        indptr = compact_indptr(re - rs)
         if vs.size == 0:
             # no pread was issued: not an on-demand read, nothing to count
-            return vs, [], []
-        if vs[0] < s or vs[-1] >= e:
-            raise IndexError(f"vertices outside block {b} range [{s}, {e})")
+            return vs, indptr, np.zeros(0, np.int32), rs
         nv = int(self.block_nverts[b])
         off = int(self.block_offsets[b])
         indices_off = off + 4 * (nv + 1)
-        rows = []
-        extents = []
-        nbytes = 0
         if self.io_coalesce_gap > 0:
-            read = lambda o, n: self._pread_exact(o, n, what=f"coalesced range block {b}")
+            what = f"coalesced range block {b}"
+            read = lambda o, n: self._pread_exact(o, n, what=what)
             lv = vs - s
             iplan = plan_reads(4 * lv, 4 * lv + 8, self.io_coalesce_gap)
-            pairs = execute_plan(iplan, read, base=off)
-            rplan_s = np.empty(vs.size, np.int64)
-            rplan_e = np.empty(vs.size, np.int64)
-            for k, buf in enumerate(pairs):
-                pair = np.frombuffer(buf, np.int32)
-                rplan_s[k], rplan_e[k] = int(pair[0]), int(pair[1])
-                extents.append((int(pair[0]), int(pair[1])))
-            rplan = plan_reads(4 * rplan_s, 4 * rplan_e, self.io_coalesce_gap)
-            for seg in execute_plan(rplan, read, base=indices_off):
-                rows.append(np.frombuffer(seg, np.int32).copy())
-            nbytes = 8 * vs.size + 4 * int((rplan_e - rplan_s).sum())
+            pairs = np.frombuffer(
+                bytearray().join(execute_plan(iplan, read, base=off)), np.int32
+            ).reshape(-1, 2)
+            bad = np.flatnonzero((pairs[:, 0] != rs) | (pairs[:, 1] != re))
+            if bad.size:
+                k = int(bad[0])
+                self._bad_pair(int(vs[k]), pairs[k].tobytes(), int(rs[k]), int(re[k]))
+            rplan = plan_reads(4 * rs, 4 * re, self.io_coalesce_gap)
+            indices = np.frombuffer(
+                bytearray().join(execute_plan(rplan, read, base=indices_off)), np.int32
+            )
             nranges = iplan.num_ranges + rplan.num_ranges
             self.ondemand_syscalls += nranges
             self.coalesced_ranges += nranges
             self.coalesce_waste_bytes += iplan.waste_bytes + rplan.waste_bytes
         else:
-            for v in vs:
-                lv = int(v) - s
-                pair = np.frombuffer(
-                    self._pread_exact(off + 4 * lv, 8, what=f"index pair v={v}"),
-                    np.int32,
-                )
-                rs, re = int(pair[0]), int(pair[1])
-                nbytes += 8
-                seg = self._pread_exact(indices_off + 4 * rs, 4 * (re - rs), what=f"row v={v}")
-                rows.append(np.frombuffer(seg, np.int32).copy())
-                extents.append((rs, re))
-                nbytes += 4 * (re - rs)
+            indices = np.empty(int(indptr[-1]), dtype=np.int32)
+            out = memoryview(indices).cast("B")
+            fd, pread, preadv, unpack = self._fd, os.pread, os.preadv, _PAIR.unpack
+            pair_offs = (off + 4 * (vs - s)).tolist()
+            row_offs = (indices_off + 4 * rs).tolist()
+            extents = list(zip(rs.tolist(), re.tolist()))
+            bounds = (4 * indptr).tolist()
+            for k, pair_off in enumerate(pair_offs):
+                raw = pread(fd, 8, pair_off)
+                if len(raw) != 8 or unpack(raw) != extents[k]:
+                    self._bad_pair(int(vs[k]), raw, *extents[k])
+                lo, hi = bounds[k], bounds[k + 1]
+                if preadv(fd, [out[lo:hi]], row_offs[k]) != hi - lo:
+                    raise BlockFileError(
+                        f"truncated block file: row of v={int(vs[k])} at offset {row_offs[k]}"
+                    )
             self.ondemand_syscalls += 2 * int(vs.size)
         self.ondemand_reads += 1
-        self.ondemand_bytes_read += nbytes
-        return vs, rows, extents
+        self.ondemand_bytes_read += 8 * int(vs.size) + 4 * int(indptr[-1])
+        return vs, indptr, indices, rs
 
-    def read_rows(self, b: int, vertices: Iterable[int]) -> Dict[int, np.ndarray]:
+    @staticmethod
+    def _bad_pair(v: int, raw: bytes, rs: int, re: int):
+        if len(raw) != 8:
+            raise BlockFileError(
+                f"truncated block file: wanted 8 bytes of index pair v={v}, got {len(raw)}"
+            )
+        raise BlockFileError(
+            f"index pair of v={v} reads {_PAIR.unpack(raw)}, but the resident "
+            f"degrees give ({rs}, {re}): the index and the degree table disagree"
+        )
+
+    def read_rows(self, b: int, vertices: np.ndarray) -> Dict[int, np.ndarray]:
         """On-demand load: ``{vertex: global neighbor ids}`` for each unique
         requested vertex of block ``b``.  The bytes read equal
         ``activated_load_bytes(vertices)`` by construction."""
-        vs, rows, _ = self._read_rows_ext(b, vertices)
-        return {int(v): seg for v, seg in zip(vs, rows)}
+        vs, indptr, indices, _ = self._read_rows_ext(b, vertices)
+        return {int(v): indices[indptr[k] : indptr[k + 1]] for k, v in enumerate(vs)}
 
-    def partial_view(self, b: int, vertices: Iterable[int]) -> BlockView:
+    def partial_view(self, b: int, vertices: np.ndarray) -> BlockView:
         """An *activated* :class:`~repro.core.graph.BlockView` of block
         ``b``: compacted local CSR over only the (unique) requested vertices
         plus the remap table — what on-demand buckets execute on.
@@ -496,78 +518,80 @@ class DiskBlockedGraph:
         like a full load's).  Mirrors ``BlockedGraph.partial_view`` — same
         view, real reads.
         """
-        vs, segs, extents = self._read_rows_ext(b, vertices)
-        alias_segs = None
+        vs, indptr, indices, rs = self._read_rows_ext(b, vertices)
+        alias_j = alias_q = None
         if self.weighted:
-            alias_segs = self._read_alias_rows(b, vs, extents)
-        return BlockView.from_rows(b, vs, segs, alias_segs)
+            alias_j, alias_q = self._read_alias_rows(b, rs, indptr)
+        return BlockView.from_flat(b, vs, indptr, indices, alias_j, alias_q)
 
-    def gather_view(self, vertices: Iterable[int]) -> BlockView:
+    def gather_view(self, vertices: np.ndarray) -> BlockView:
         """A cross-block activated view (``block_id == -1``): per-vertex
         partial reads grouped by owning block.  Blocks hold contiguous
         vertex ranges, so concatenating the per-block (sorted) rows in
         block order yields a globally sorted remap table.  Real bytes are
         tallied like any on-demand read."""
-        vs_all = np.unique(np.asarray(list(vertices), dtype=np.int64))
+        vs_all = np.unique(np.asarray(vertices, dtype=np.int64))
         owners = block_of(self.block_starts, vs_all)
-        all_vs = []
-        all_segs = []
-        all_alias = [] if self.weighted else None
-        for b in np.unique(owners):
-            sub = vs_all[owners == b]
-            vs, segs, extents = self._read_rows_ext(int(b), sub)
-            all_vs.append(vs)
-            all_segs.extend(segs)
-            if self.weighted:
-                all_alias.extend(self._read_alias_rows(int(b), vs, extents))
-        vids = np.concatenate(all_vs) if all_vs else np.zeros(0, np.int64)
-        return BlockView.from_rows(-1, vids, all_segs, all_alias)
+        views = [self.partial_view(int(b), vs_all[owners == b]) for b in np.unique(owners)]
+        deg = np.concatenate([np.diff(v.indptr) for v in views]) if views else np.zeros(0, np.int64)
+        cat = lambda name: np.concatenate([getattr(v, name) for v in views] or [np.zeros(0)])
+        return BlockView.from_flat(
+            -1,
+            vs_all,
+            compact_indptr(deg),
+            cat("indices"),
+            cat("alias_j") if self.weighted else None,
+            cat("alias_q") if self.weighted else None,
+        )
 
-    def _read_alias_rows(self, b: int, vs: np.ndarray, extents):
-        """Partial reads of the rows' alias_j/alias_q segments, at the edge
-        ranges ``extents`` already fetched by :meth:`_read_rows_ext` — no
+    def _read_alias_rows(self, b: int, rs: np.ndarray, indptr: np.ndarray):
+        """Partial reads of the rows' alias_j/alias_q segments into flat
+        arrays, at the within-block edge starts ``rs`` (and the compact
+        offsets ``indptr``) already known to :meth:`_read_rows_ext` — no
         second index-pair read per vertex.  With the planner on, the alias
         extents parallel the row extents, so one plan covers both regions
         (executed twice with different base offsets)."""
         ne = int(self.block_nedges[b])
         nv = int(self.block_nverts[b])
         off = int(self.block_offsets[b])
-        aux_off = off + 4 * (nv + 1) + 4 * ne  # weights, then alias_j, alias_q
-        out = []
-        nbytes = 0
-        if self.io_coalesce_gap > 0 and len(vs):
-            read = lambda o, n: self._pread_exact(o, n, what=f"coalesced alias block {b}")
-            rs = np.asarray([x for x, _ in extents], np.int64)
-            re = np.asarray([x for _, x in extents], np.int64)
-            aplan = plan_reads(4 * rs, 4 * re, self.io_coalesce_gap)
-            j_bufs = execute_plan(aplan, read, base=aux_off + 4 * ne)
-            q_bufs = execute_plan(aplan, read, base=aux_off + 8 * ne)
-            for jb, qb in zip(j_bufs, q_bufs):
-                out.append(
-                    (np.frombuffer(jb, np.int32).copy(), np.frombuffer(qb, np.float32).copy())
-                )
-            nbytes = 8 * int((re - rs).sum())
+        j_off = off + 4 * (nv + 1) + 8 * ne  # after the CSR slice and the weights
+        q_off = j_off + 4 * ne
+        nnz = int(indptr[-1])
+        if self.io_coalesce_gap > 0 and rs.size:
+            what = f"coalesced alias block {b}"
+            read = lambda o, n: self._pread_exact(o, n, what=what)
+            aplan = plan_reads(4 * rs, 4 * (rs + np.diff(indptr)), self.io_coalesce_gap)
+            alias_j = np.frombuffer(
+                bytearray().join(execute_plan(aplan, read, base=j_off)), np.int32
+            )
+            alias_q = np.frombuffer(
+                bytearray().join(execute_plan(aplan, read, base=q_off)), np.float32
+            )
             self.ondemand_syscalls += 2 * aplan.num_ranges
             self.coalesced_ranges += 2 * aplan.num_ranges
             self.coalesce_waste_bytes += 2 * aplan.waste_bytes
         else:
-            for v, (rs, re) in zip(vs, extents):
-                rl = re - rs
-                aj = np.frombuffer(
-                    self._pread_exact(aux_off + 4 * ne + 4 * rs, 4 * rl, what=f"alias_j v={v}"),
-                    np.int32,
-                ).copy()
-                aq = np.frombuffer(
-                    self._pread_exact(aux_off + 8 * ne + 4 * rs, 4 * rl, what=f"alias_q v={v}"),
-                    np.float32,
-                ).copy()
-                out.append((aj, aq))
-                nbytes += 8 * rl
-            self.ondemand_syscalls += 2 * len(vs)
-        self.aux_bytes_read += nbytes
-        return out
+            alias_j = np.empty(nnz, dtype=np.int32)
+            alias_q = np.empty(nnz, dtype=np.float32)
+            out_j = memoryview(alias_j).cast("B")
+            out_q = memoryview(alias_q).cast("B")
+            fd, preadv = self._fd, os.preadv
+            cells = (4 * rs).tolist()
+            bounds = (4 * indptr).tolist()
+            for k, cell in enumerate(cells):
+                lo, hi = bounds[k], bounds[k + 1]
+                if (
+                    preadv(fd, [out_j[lo:hi]], j_off + cell) != hi - lo
+                    or preadv(fd, [out_q[lo:hi]], q_off + cell) != hi - lo
+                ):
+                    raise BlockFileError(
+                        f"truncated block file: alias row {k} of block {b} at cell {cell // 4}"
+                    )
+            self.ondemand_syscalls += 2 * int(rs.size)
+        self.aux_bytes_read += 8 * nnz
+        return alias_j, alias_q
 
-    def partial_block(self, b: int, vertices: Iterable[int]) -> ResidentBlock:
+    def partial_block(self, b: int, vertices: np.ndarray) -> ResidentBlock:
         """An *activated-vertex view* of block ``b``: a padded
         :class:`ResidentBlock` holding only the requested rows, compacted.
 
@@ -575,23 +599,16 @@ class DiskBlockedGraph:
         rows hold the same neighbor lists a full load would.  Reads only the
         requested bytes (tallied in ``ondemand_bytes_read``).
         """
-        rows = self.read_rows(b, vertices)
+        vs, vptr, vind, _ = self._read_rows_ext(b, vertices)
         nv = int(self.block_nverts[b])
         s = int(self.block_starts[b])
-        indptr = np.zeros(self.max_block_verts + 1, dtype=np.int32)
-        chunks = []
-        fill = 0
-        for lv in range(nv):
-            indptr[lv] = fill
-            seg = rows.get(s + lv)
-            if seg is not None:
-                chunks.append(seg)
-                fill += seg.size
-        indptr[nv:] = fill
+        deg = np.zeros(nv, dtype=np.int64)
+        deg[vs - s] = np.diff(vptr)
+        fill = int(vptr[-1])
+        indptr = np.full(self.max_block_verts + 1, fill, dtype=np.int32)
+        indptr[: nv + 1] = compact_indptr(deg)
         indices = np.full(self.max_block_edges, -1, dtype=np.int32)
-        if chunks:
-            cat = np.concatenate(chunks)
-            indices[: cat.size] = cat
+        indices[:fill] = vind
         return ResidentBlock(b, s, nv, fill, indptr, indices)
 
     # -- reconstruction --------------------------------------------------------

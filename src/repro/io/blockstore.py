@@ -342,11 +342,12 @@ class BlockStore:
                 with self.stats.span("blocks.prefetch_wait"):
                     base = fut.result()
             if base is not None:
-                in_req = np.isin(base.vids, vs)
-                if in_req.all():
+                present = base.has_vertices(vs)
+                # every prefetched vertex was requested: the base is a subset
+                if int(present.sum()) == base.nverts:
                     self.partial_prefetch_hits += 1
                     self.stats.note_overlapped(self.bg.activated_load_bytes(base.vids))
-                    missing = vs[~base.has_vertices(vs)]
+                    missing = vs[~present]
                     if missing.size:
                         base = self._extend(base, missing)
                     return base
@@ -357,7 +358,8 @@ class BlockStore:
 
     def _extend(self, view: BlockView, vertices: np.ndarray) -> BlockView:
         extra = self._build_partial(view.block_id, vertices)
-        return view.extended(extra)
+        with self.stats.span("blocks.merge", view.nverts + extra.nverts):
+            return view.extended(extra)
 
     def extend_view(self, view: BlockView, vertices: np.ndarray) -> BlockView:
         """Mid-advance extension gather: append the rows of ``vertices`` to

@@ -189,6 +189,92 @@ def test_read_rows_rejects_foreign_vertices(disk_graph):
             dg.read_rows(0, [outside])
 
 
+def _vertex_set(name, s, e, rng):
+    return {
+        "empty": np.zeros(0, np.int64),
+        "one": np.array([s + (e - s) // 2]),
+        "block": np.arange(s, e),
+        "subset": rng.integers(s, e, size=(e - s) // 3),  # duplicates dedupe
+    }[name]
+
+
+@pytest.mark.parametrize("vset", ["empty", "one", "block", "subset"])
+@pytest.mark.parametrize("weighted", [False, True], ids=["unweighted", "weighted"])
+def test_flat_reader_matches_ram_view_and_reads(
+    small_blocked, weighted_blocked, tmp_path, monkeypatch, weighted, vset
+):
+    """The gap-0 partial view is the in-RAM view bit for bit, read as the
+    per-vertex reference reads: index pair then row per vertex in vid order
+    (then alias_j, alias_q per vertex), 2 (4 weighted) syscalls a vertex,
+    exactly activated_load_bytes of index + CSR."""
+    bg = weighted_blocked if weighted else small_blocked
+    if weighted:
+        bg.ensure_alias()
+    path = str(tmp_path / BLOCK_FILE_NAME)
+    write_block_file(bg, path)
+    b = 2
+    s, e = int(bg.block_starts[b]), int(bg.block_starts[b + 1])
+    verts = _vertex_set(vset, s, e, np.random.default_rng(4))
+    reads = []
+    pread, preadv = os.pread, os.preadv
+    monkeypatch.setattr(os, "pread", lambda fd, n, off: reads.append((off, n)) or pread(fd, n, off))
+    monkeypatch.setattr(
+        os, "preadv", lambda fd, bufs, off: reads.append((off, len(bufs[0]))) or preadv(fd, bufs, off)
+    )
+    with DiskBlockedGraph(path) as dg:
+        base = int(dg.block_offsets[b])
+        reads.clear()
+        got = dg.partial_view(b, verts)
+        want = bg.partial_view(b, verts)
+        for name in ("vids", "indptr", "indices", "alias_j", "alias_q"):
+            g, w = getattr(got, name), getattr(want, name)
+            if not weighted and name.startswith("alias"):
+                assert g is None and w is None
+                continue
+            assert g.dtype == w.dtype, name
+            np.testing.assert_array_equal(g, w, err_msg=name)
+        assert got.kind == "activated" and got.block_id == b
+
+        vs = np.unique(verts)
+        nv, ne = int(bg.block_nverts[b]), int(bg.block_nedges[b])
+        rs = bg.graph.indptr[vs] - bg.graph.indptr[s]
+        deg = bg.graph.degrees[vs]
+        csr = base + 4 * (nv + 1)
+        expect = []
+        for v, r, d in zip(vs.tolist(), rs.tolist(), deg.tolist()):
+            expect += [(base + 4 * (v - s), 8), (csr + 4 * r, 4 * d)]
+        if weighted:
+            for r, d in zip(rs.tolist(), deg.tolist()):
+                expect += [(csr + 8 * ne + 4 * r, 4 * d), (csr + 12 * ne + 4 * r, 4 * d)]
+        assert reads == expect
+        assert dg.ondemand_syscalls == (4 if weighted else 2) * vs.size
+        assert dg.ondemand_bytes_read == dg.activated_load_bytes(verts)
+        assert dg.aux_bytes_read == (8 * int(deg.sum()) if weighted else 0)
+        assert dg.ondemand_reads == (1 if vs.size else 0)
+
+
+@pytest.mark.parametrize("gap", [0, 4096], ids=["per-vertex", "planner"])
+def test_index_pair_disagreeing_with_degrees_names_the_vertex(
+    small_blocked, disk_graph, tmp_path, gap
+):
+    """An index pair that disagrees with the resident degree table fails
+    the on-demand read, naming the vertex, instead of sizing a wrong view."""
+    path, _ = _copy(disk_graph, tmp_path, "bad_pair.grb")
+    b = 1
+    s = int(small_blocked.block_starts[b])
+    v = s + 3
+    with DiskBlockedGraph(path) as dg:
+        entry = int(dg.block_offsets[b]) + 4 * (v - s + 1)  # v's row end
+    with open(path, "r+b") as f:
+        f.seek(entry)
+        end = int(np.frombuffer(f.read(4), np.int32)[0])
+        f.seek(entry)
+        f.write(np.int32(end + 1).tobytes())
+    with DiskBlockedGraph(path, io_coalesce_gap=gap) as dg:
+        with pytest.raises(BlockFileError, match=f"v={v}\\b"):
+            dg.partial_view(b, [s, v, s + 9])
+
+
 # ---------------------------------------------------------------------------
 # corruption / truncation error paths
 # ---------------------------------------------------------------------------
